@@ -2,12 +2,9 @@
 
 Prints ``name,us_per_call,derived`` CSV at the end (per harness contract).
 
-Bench modules are imported lazily: an entry whose module cannot be
-imported (an optional engine dependency missing from the environment,
-e.g. JAX on a CPU-only box) is **skipped with a reason** instead of
-taking the whole sweep down — ``make``-driven sweeps survive partial
-environments.  A bench that imports but *fails to run* still fails the
-harness; only missing dependencies downgrade to skips.
+Bench modules are imported lazily, one entry at a time; an entry whose
+module fails to import stops the run, and a bench that imports but
+*fails to run* is reported and fails the harness at the end.
 
 Every completed bench run is appended to ``BENCH_<name>.json`` at the
 repo root via :func:`record_bench` — an append-mode trajectory of
@@ -113,17 +110,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     rows = []
     failed = []
-    skipped = []
     for name, modpath in BENCHES:
         if args.only and args.only != name:
             continue
-        try:
-            mod = importlib.import_module(modpath)
-        except ImportError as e:
-            # optional engine dependency absent: degrade to a skip
-            skipped.append((name, f"import failed: {e}"))
-            print(f"SKIP {name}: {e}", file=sys.stderr)
-            continue
+        mod = importlib.import_module(modpath)
         try:
             bench_rows = list(mod.run())
         except Exception as e:
@@ -136,8 +126,6 @@ def main(argv=None) -> None:
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
-    if skipped:
-        print(f"SKIPPED benches: {skipped}", file=sys.stderr)
     if failed:
         print(f"FAILED benches: {failed}", file=sys.stderr)
         sys.exit(1)

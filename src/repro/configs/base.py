@@ -85,8 +85,8 @@ class ModelConfig:
     moe_partial_ep: bool = False       # serving: d-sliced partial-sum expert
                                        # compute, no FSDP weight gather
     use_pallas_decode: bool = False    # decode attention via the Pallas
-                                       # flash-decode kernel (TPU; interpret
-                                       # mode on CPU)
+                                       # flash-decode kernel (Mosaic on TPU,
+                                       # interpreter on the CPU backend)
     use_pallas_prefill: bool = False   # prefill attention via the Pallas
                                        # swa_prefill kernel (full causal ==
                                        # window >= S; serving path only)

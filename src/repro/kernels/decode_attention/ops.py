@@ -7,18 +7,13 @@ import jax
 
 from repro.kernels.decode_attention.decode_attention import \
     decode_attention_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+from repro.kernels.platform import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("block_s",))
 def decode_attention(q, k, v, lengths, block_s: int = 512):
-    """Flash-decode GQA attention.  q: (B, KV, G, D); k/v: (B, S, KV, D);
-    lengths: (B,) int32 valid cache lengths.  Returns (B, KV, G, D)."""
+    """Flash-decode GQA attention.  q: (B, KV, G, D); k/v: (B, KV, S, D)
+    (head-major cache); lengths: (B,) int32 valid cache lengths.
+    Returns (B, KV, G, D)."""
     return decode_attention_pallas(q, k, v, lengths, block_s=block_s,
-                                   interpret=not _on_tpu())
+                                   interpret=interpret_mode())

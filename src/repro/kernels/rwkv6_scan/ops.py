@@ -5,14 +5,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.rwkv6_scan.rwkv6_scan import rwkv6_scan_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("block_t",))
@@ -20,4 +14,4 @@ def rwkv6_scan(r, k, v, w, u, s0, block_t: int = 256):
     """WKV6 recurrence.  r,k,v,w: (B,T,H,D); u: (H,D); s0: (B,H,D,D).
     Returns (y, s_final)."""
     return rwkv6_scan_pallas(r, k, v, w, u, s0, block_t=block_t,
-                             interpret=not _on_tpu())
+                             interpret=interpret_mode())

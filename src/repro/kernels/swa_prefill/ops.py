@@ -4,28 +4,19 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.swa_prefill.swa_prefill import swa_prefill_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("window", "block"))
 def swa_prefill_attention(q, k, v, window: int, block: int = 256):
     """Causal SWA prefill.  q: (B, S, H, D); k, v: (B, S, KV, D) with
-    H % KV == 0 (GQA groups are folded into the head axis by repeating
-    K/V — the kernel sees equal head counts).  Returns (B, S, H, D)."""
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    g = h // kv
-    if g > 1:
-        k = jnp.repeat(k, g, axis=2)
-        v = jnp.repeat(v, g, axis=2)
-    return swa_prefill_pallas(q, k, v, window=window, block_q=block,
-                              block_k=block, interpret=not _on_tpu())
+    H % KV == 0.  The kernel takes head-major operands; query head h
+    attends kv head h // (H // KV).  Returns (B, S, H, D)."""
+    def head_major(x):
+        return x.transpose(0, 2, 1, 3)
+    out = swa_prefill_pallas(head_major(q), head_major(k), head_major(v),
+                             window=window, block_q=block, block_k=block,
+                             interpret=interpret_mode())
+    return head_major(out)

@@ -2,11 +2,15 @@
 
 The pure-jnp blocked attention computes every (q_block, kv_block) pair and
 masks — O(S^2) work even when the window W << S.  This kernel's grid is
-(B, KV, S/block_q, W/block_k + 1): for each q block only the kv blocks that
+(B, H, S/block_q, W/block_k + 1): for each q block only the kv blocks that
 can intersect its window are visited, so prefill work is O(S * W) — an
 8x reduction for h2o-danube's prefill_32k (W=4096, S=32768).
 
 TPU mapping:
+* operands are head-major (B, heads, S, D), so every tile's last two dims
+  are (block, D) — a legal Mosaic block (multiples of (8, 128) or the full
+  array dims); query head h reads kv head h // G (GQA without repeating
+  K/V in HBM);
 * the kv BlockSpec index_map computes the ABSOLUTE kv block
   `qi + wi - n_w + 1` (clamped at 0) — the harness streams exactly the
   window-diagonal band HBM->VMEM;
@@ -43,9 +47,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(expected >= 0)
     def _work():
-        q = q_ref[0, :, 0].astype(jnp.float32) * scale   # (bq*G? no: bq, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (bk, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale      # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)              # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -68,18 +72,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(wi == n_w - 1)
     def _finish():
-        o_ref[0, :, 0] = (acc_ref[...]
-                          / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...]
+                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def swa_prefill_pallas(q, k, v, *, window: int, block_q: int = 256,
                        block_k: int = 256, interpret: bool = True):
-    """Causal sliding-window attention, one kv head group at a time.
+    """Causal sliding-window GQA attention on head-major operands.
 
-    q: (B, S, H, D) with H == KV heads here (call per-group or with GQA
-    groups folded into batch by the ops wrapper); k, v: (B, S, H, D).
-    Returns (B, S, H, D)."""
-    b, s, h, d = q.shape
+    q: (B, H, S, D); k, v: (B, KV, S, D) with H % KV == 0.
+    Returns (B, H, S, D)."""
+    b, h, s, d = q.shape
+    g = h // k.shape[1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     assert block_q == block_k, "kernel requires equal q/kv block sizes"
@@ -91,21 +95,22 @@ def swa_prefill_pallas(q, k, v, *, window: int, block_q: int = 256,
     kernel = functools.partial(_kernel, block_q=block_q, block_k=block_k,
                                n_w=n_w, window=window, scale=d ** -0.5)
 
+    def q_index(bi, hi, qi, wi):
+        return (bi, hi, qi, 0)
+
     def kv_index(bi, hi, qi, wi):
-        return (bi, jnp.maximum(qi + wi - (n_w - 1), 0), hi, 0)
+        return (bi, hi // g, jnp.maximum(qi + wi - (n_w - 1), 0), 0)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda bi, hi, qi, wi: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d), kv_index),
-            pl.BlockSpec((1, block_k, 1, d), kv_index),
+            pl.BlockSpec((1, 1, block_q, d), q_index),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, hi, qi, wi: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), q_index),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),

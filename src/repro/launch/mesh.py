@@ -7,18 +7,20 @@ device initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod (TPU v5e pod slice); 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_small_mesh(data: int = 2, model: int = 2):
     """Test mesh for CI-scale integration tests (8 fake devices or fewer)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_name(mesh) -> str:

@@ -108,7 +108,8 @@ def run_live(args) -> dict:
         arrivals.append((req, rng.integers(
             0, cfg.vocab_size, args.prompt_len).astype(np.int32)))
     report = server.run(arrivals, horizon=args.duration + 30)
-    res = {"n": report.n_requests, "violations": report.n_violations,
+    res = {"sent": len(arrivals), "served": len(server.monitor.completed),
+           "n": report.n_requests, "violations": report.n_violations,
            "violation_rate": report.violation_rate,
            "p50": report.p50, "p99": report.p99,
            "decisions": len(report.decisions or ()),
@@ -199,7 +200,8 @@ def run_scenario_mode(args) -> dict:
     return out
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Parse ``argv`` and run the selected mode; returns its result."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("sim", "live", "scenario"),
                     default="sim")
@@ -275,15 +277,15 @@ def main(argv=None):
     if args.scenario or args.mode == "scenario":
         if not args.scenario:
             ap.error("--mode scenario requires --scenario <name>")
-        run_scenario_mode(args)
-        return
+        return run_scenario_mode(args)
     args.rps = 20.0 if args.rps is None else args.rps
     args.duration = 600.0 if args.duration is None else args.duration
     if args.mode == "sim":
-        run_sim(args)
-    else:
-        run_live(args)
+        return run_sim(args)
+    return run_live(args)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

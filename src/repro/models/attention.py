@@ -191,7 +191,8 @@ def attention_decode(params, x: Array, cache: dict, cache_index: Array,
                      num_heads=None, num_kv_heads=None, head_dim=None):
     """Single-token decode.  x: (B, 1, d_model).
 
-    cache: {"k": (B, S, KV, D), "v": ...} — S is the window size for SWA
+    cache: {"k": (B, KV, S, D), "v": ...} — head-major, so the Pallas
+    decode kernel streams (block_s, D) tiles; S is the window size for SWA
     (ring buffer) or max_seq for full attention.  Keys are cached post-RoPE.
     Returns (y, new_cache).
     """
@@ -205,12 +206,14 @@ def attention_decode(params, x: Array, cache: dict, cache_index: Array,
     if cfg.rope_kind in ("standard", "mrope"):
         q, k = _rope_qk(q, k, positions, cfg)
 
-    s_cache = cache["k"].shape[1]
+    s_cache = cache["k"].shape[2]
     slot = cache_index % s_cache if window > 0 else cache_index
-    ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                      (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                      (0, slot, 0, 0))
+    ck = jax.lax.dynamic_update_slice(
+        cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
+        (0, 0, slot, 0))
+    cv = jax.lax.dynamic_update_slice(
+        cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
+        (0, 0, slot, 0))
 
     # validity mask over cache slots
     j = jnp.arange(s_cache)
@@ -223,7 +226,7 @@ def attention_decode(params, x: Array, cache: dict, cache_index: Array,
 
     g = h // kvh
     if (cfg.use_pallas_decode and window == 0 and cfg.logit_softcap == 0
-            and d % 8 == 0):
+            and d % 8 == 0 and (s_cache <= 512 or s_cache % 512 == 0)):
         # Pallas flash-decode kernel path (kernels/decode_attention):
         # contiguous cache [0..index] -> lengths mask
         from repro.kernels.decode_attention.ops import decode_attention
@@ -235,12 +238,12 @@ def attention_decode(params, x: Array, cache: dict, cache_index: Array,
         y = linear(out, params["wo"])
         return y, {"k": ck, "v": cv}
     qf = (q.reshape(b, kvh, g, d) * (d ** -0.5)).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qf, ck.astype(jnp.float32))
+    scores = jnp.einsum("bkgd,bksd->bkgs", qf, ck.astype(jnp.float32))
     if cfg.logit_softcap > 0:
         scores = softcap(scores, cfg.logit_softcap)
     scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, cv.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bksd->bkgd", p, cv.astype(jnp.float32))
     out = out.reshape(b, 1, h * d).astype(x.dtype)
     y = linear(out, params["wo"])
     return y, {"k": ck, "v": cv}
@@ -251,7 +254,7 @@ def init_attention_cache(cfg: ModelConfig, batch: int, seq: int, dtype, *,
     kvh = num_kv_heads or cfg.num_kv_heads
     d = head_dim or cfg.head_dim
     s = min(seq, window) if window > 0 else seq
-    shape = (batch, s, kvh, d)
+    shape = (batch, kvh, s, d)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 

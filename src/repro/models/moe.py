@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.common import Array, dense_init
 from repro.models.mlp import init_mlp, mlp_fwd
-from repro.models.sharding import shard_map_compat
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
@@ -193,7 +192,7 @@ def moe_fwd_ep(params, x: Array, cfg: ModelConfig, mesh: jax.sharding.Mesh,
 
     xt = x.reshape(b * s, d)
     dspec = P(data_axes, None)
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(dspec, P(), P(), P(model_axis, data_axes, None),
                   P(model_axis, data_axes, None), P(model_axis, None, data_axes)),
@@ -298,7 +297,7 @@ def _moe_fwd_partial_ep(params, x: Array, cfg: ModelConfig, mesh,
 
     xt = x.reshape(t, d)
     dspec = P(data_axes, None)
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(dspec, P(), P(), P(model_axis, data_axes, None),
                   P(model_axis, data_axes, None),

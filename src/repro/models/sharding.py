@@ -18,23 +18,6 @@ from jax.sharding import PartitionSpec as P
 FSDP = ("pod", "data")
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable ``shard_map``.
-
-    JAX >= 0.6 exposes ``jax.shard_map`` (replication checking controlled
-    by ``check_vma``); the pinned 0.4.x line only has
-    ``jax.experimental.shard_map.shard_map``, where the same switch is
-    spelled ``check_rep``.  Resolve whichever exists and translate the
-    kwarg so call sites can use the modern spelling everywhere.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
 # (path regex, spec over trailing dims)
 PARAM_RULES: list[tuple[str, P]] = [
     # embeddings / heads
@@ -86,10 +69,11 @@ PARAM_RULES: list[tuple[str, P]] = [
 ]
 
 CACHE_RULES: list[tuple[str, P]] = [
-    # KV caches: batch over data axes, heads over model
-    (r"kv/[kv]$", P(FSDP, None, "model", None)),
+    # KV caches: batch over data axes, heads over model (self-attention
+    # caches are head-major (B, KV, S, D); cross caches are (B, S, KV, D))
+    (r"kv/[kv]$", P(FSDP, "model", None, None)),
     (r"cross/[kv]$", P(FSDP, None, "model", None)),
-    (r"shared.*/[kv]$", P(FSDP, None, "model", None)),
+    (r"shared.*/[kv]$", P(FSDP, "model", None, None)),
     # MLA latent cache: batch over data only (latent dim small)
     (r"kv/c_kv$", P(FSDP, None, None)),
     (r"kv/k_rope$", P(FSDP, None, None)),
@@ -186,9 +170,9 @@ def param_specs(params_shape: Any, mesh, fsdp: bool = True) -> Any:
 # partitions DUS on a sharded dim without gathering (verified in the perf
 # log).  Head-dim sharding is dropped (kv heads rarely divide 16).
 CACHE_RULES_SEQSHARD: list[tuple[str, P]] = [
-    (r"kv/[kv]$", P(FSDP, "model", None, None)),
+    (r"kv/[kv]$", P(FSDP, None, "model", None)),
     (r"cross/[kv]$", P(FSDP, "model", None, None)),
-    (r"shared.*/[kv]$", P(FSDP, "model", None, None)),
+    (r"shared.*/[kv]$", P(FSDP, None, "model", None)),
     (r"kv/c_kv$", P(FSDP, "model", None)),
     (r"kv/k_rope$", P(FSDP, "model", None)),
 ] + CACHE_RULES[5:]

@@ -136,22 +136,24 @@ def block_fwd(p, x, ctx, kind, cfg: ModelConfig, mesh):
 # -- prefill: same math, but also build the decode cache ---------------------
 
 def _write_kv_cache(k, v, positions, cache_size, window):
-    """Arrange full-sequence K/V (B,S,KV,D) into a decode cache.
+    """Arrange full-sequence K/V (B,S,KV,D) into a head-major decode cache
+    (B,KV,cache,D).
 
-    Full attention: cache[:, :S] = kv (cache_size >= S).
+    Full attention: cache[:, :, :S] = kv (cache_size >= S).
     SWA: ring buffer of size window — slot p%W holds position p (last W)."""
     b, s, kvh, d = k.shape
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if window > 0:
         w = min(window, cache_size)
         take = min(s, w)
-        ks_, vs_ = k[:, -take:], v[:, -take:]
+        ks_, vs_ = k[:, :, -take:], v[:, :, -take:]
         pos = positions[0, -take:] % w
-        ck = jnp.zeros((b, w, kvh, d), k.dtype).at[:, pos].set(ks_)
-        cv = jnp.zeros((b, w, kvh, d), v.dtype).at[:, pos].set(vs_)
+        ck = jnp.zeros((b, kvh, w, d), k.dtype).at[:, :, pos].set(ks_)
+        cv = jnp.zeros((b, kvh, w, d), v.dtype).at[:, :, pos].set(vs_)
         return {"k": ck, "v": cv}
     pad = cache_size - s
-    ck = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    cv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    ck = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    cv = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     return {"k": ck, "v": cv}
 
 

@@ -300,8 +300,8 @@ class JaxBackend(_PooledBackend):
     """Live execution over a pre-jitted ``(c, b)`` executable table.
 
     ``step_fns[(c, b)](stacked_payload)`` must be ready to call (compiled
-    at deploy — that is what makes the resize in-place; on the TPU target
-    each entry is the same step compiled on a c-chip submesh).  ``clock``
+    at deploy — that is what makes the resize in-place; every c maps to
+    the same single-device step for now).  ``clock``
     selects how virtual time advances after a batch:
 
     * ``"measured"`` — by the measured wall latency (the serving default);
@@ -310,8 +310,8 @@ class JaxBackend(_PooledBackend):
       *provided both backends charge the same resize_penalty* (real
       outputs are still produced and the measured-vs-predicted residual
       is still recorded).  Note the defaults differ deliberately:
-      JaxBackend charges 0 — the dictionary flip is free on this
-      container — while SimBackend models the TPU weight re-gather
+      JaxBackend charges 0 — the dictionary flip is free while every c
+      shares one executable — while SimBackend models the TPU weight re-gather
       (5 ms); parity runs must align them, as the parity test does.
 
     Multi-slot pools are supported: a horizontal policy (FA2-style) can
@@ -842,36 +842,38 @@ def calibrate_step_fns(fns: Dict[tuple[int, int], Callable],
 def build_llm_step_fns(model, params, c_set: Sequence[int],
                        b_set: Sequence[int], prompt_len: int,
                        gen_tokens: int = 8):
-    """Executable table for short-generation LLM serving on the reduced
-    models: each entry prefills the prompt batch and decodes gen_tokens.
+    """Executable table for short-generation LLM serving: each entry
+    prefills the prompt batch and decodes gen_tokens.
 
-    On TPU each (c, b) would be compiled on its c-chip submesh; on CPU the
-    same jitted fn backs every c (see ``JaxBackend``).
+    Every c maps to the same single-device fn per b (see ``JaxBackend``).
+    Entries are ``functools.partial`` over ``params``, so the weights are
+    an argument, not a constant copied into every executable.
     """
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    def make(b):
-        def fn(tokens):
-            logits, cache = model.prefill(params, {"tokens": tokens},
-                                          cache_len=prompt_len + gen_tokens)
-            def body(carry, _):
-                cache, tok = carry
-                lg, cache = model.decode_step(params, cache, tok)
-                nxt = jnp.argmax(
-                    lg[:, :model.cfg.vocab_size], axis=-1
-                ).astype(jnp.int32)[:, None]
-                return (cache, nxt), nxt[:, 0]
-            first = jnp.argmax(logits[:, :model.cfg.vocab_size],
-                               axis=-1).astype(jnp.int32)[:, None]
-            (_, _), toks = jax.lax.scan(body, (cache, first),
-                                        None, length=gen_tokens)
-            return toks.T  # (b, gen_tokens)
-        return jax.jit(fn)
+    @jax.jit
+    def generate(params, tokens):
+        logits, cache = model.prefill(params, {"tokens": tokens},
+                                      cache_len=prompt_len + gen_tokens)
+        def body(carry, _):
+            cache, tok = carry
+            lg, cache = model.decode_step(params, cache, tok)
+            nxt = jnp.argmax(
+                lg[:, :model.cfg.vocab_size], axis=-1
+            ).astype(jnp.int32)[:, None]
+            return (cache, nxt), nxt[:, 0]
+        first = jnp.argmax(logits[:, :model.cfg.vocab_size],
+                           axis=-1).astype(jnp.int32)[:, None]
+        (_, _), toks = jax.lax.scan(body, (cache, first),
+                                    None, length=gen_tokens)
+        return toks.T  # (b, gen_tokens)
 
     fns = {}
     for b in b_set:
-        jitted = make(b)
+        jitted = functools.partial(generate, params)
         for c in c_set:
             fns[(c, b)] = jitted
     return fns
@@ -923,8 +925,8 @@ def make_live_server(arch: str = "smollm-135m-reduced", *,
 def toy_step_fns(c_set: Sequence[int], b_set: Sequence[int],
                  dim: int = 32, seed: int = 0):
     """Minimal jitted (c, b) table — a tanh layer — for exercising the
-    JaxBackend cheaply.  Every c shares the same computation on this CPU
-    container, exactly like ``build_llm_step_fns``."""
+    JaxBackend cheaply.  Every c shares the same computation, exactly like
+    ``build_llm_step_fns``."""
     import jax
     import jax.numpy as jnp
     w = jnp.asarray(np.random.default_rng(seed)

@@ -751,7 +751,7 @@ class FastSession(_ColumnSession):
         self.events_processed += n_events
         self.now = max(self.now, t)
 
-    # spongelint: inline-of repro.serving.api.ScenarioRunner._dispatch pin=bb8870a3cacd
+    # spongelint: inline-of repro.serving.api.ScenarioRunner._dispatch pin=1ec388f9ac90
     def _dispatch(self, t: float) -> None:
         """Slack-aware EDF dispatch over every slot (the FastSimRunner
         rules, verbatim: fill toward b, release a partial batch only
@@ -1211,7 +1211,7 @@ class FleetSession(_ColumnSession):
         self.events_processed += n_events
         self.now = max(self.now, t)
 
-    # spongelint: inline-of repro.serving.session.FastSession._dispatch pin=c5e1fc10d215
+    # spongelint: inline-of repro.serving.session.FastSession._dispatch pin=4a61b16de14b
     def _dispatch(self, t: float) -> None:
         """Per-replica slack-aware EDF dispatch (FleetFastSimRunner
         rules, verbatim)."""

@@ -548,7 +548,7 @@ class TenantFastRunner(_TenantRunnerBase):
         self.events_processed = n_events
         return self._finalize(finishes, horizon)
 
-    # spongelint: inline-of repro.serving.session.FleetSession._dispatch pin=3453d8c8e7ff
+    # spongelint: inline-of repro.serving.session.FleetSession._dispatch pin=df97912017f2
     def _dispatch(self, t: float, finishes, events, seq, busy_wake,
                   slack_wake) -> None:
         """Per-replica slack-aware EDF dispatch (the fleet fast-path

@@ -18,10 +18,10 @@ by masking (their slots keep stepping as padding — the real cost an
 engine pays without cache compaction) and the gang ends when the
 longest stream finishes.  Everything is jitted per ``(c, b)`` exactly
 like the fixed-work executable table, so applying a Decision stays an
-O(1) dictionary flip (the in-place vertical scaling mechanism; on the
-TPU target each entry is the same step compiled on a c-chip submesh —
-on this CPU container the kernels run in interpret mode and every c
-shares the computation, so vertical scaling affects scheduling only).
+O(1) dictionary flip (the in-place vertical scaling mechanism).  Every c
+maps to the same single-device executable for now, so vertical scaling
+affects scheduling only; on a TPU the kernels are compiled by Mosaic, on
+the CPU backend they run in the Pallas interpreter.
 
 ``calibrate_token_fns`` profiles the two tables once and fits a
 ``TokenCostModel``, which closes the loop: the solver plans token
@@ -30,6 +30,7 @@ compositions on the same cost surface the kernels exhibit.
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -49,33 +50,32 @@ def build_token_step_fns(model, params, c_set: Sequence[int],
     ``prefill_fns[(c, b)](tokens)`` maps (b, prompt_len) int32 prompts to
     ``(first_token (b,), gang_cache)``; ``decode_fns[(c, b)](cache, tok)``
     advances every slot one token.  The cache holds
-    ``prompt_len + max_decode + 1`` positions per slot.  On TPU each
-    (c, b) entry would be compiled on its c-chip submesh; on CPU the same
-    jitted fn backs every c (see the module docstring).
+    ``prompt_len + max_decode + 1`` positions per slot.  Every c maps to
+    the same jitted fn per b (see the module docstring).  Each entry is a
+    ``functools.partial`` of a jitted function over ``params``: the
+    weights are an argument, not a constant copied into every executable.
     """
     import jax
     import jax.numpy as jnp
     cache_len = prompt_len + max_decode + 1
     vocab = model.cfg.vocab_size
 
-    def make_prefill(b):
-        def fn(tokens):
-            logits, cache = model.prefill(params, {"tokens": tokens},
-                                          cache_len=cache_len)
-            first = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
-            return first, cache
-        return jax.jit(fn)
+    @jax.jit
+    def prefill(params, tokens):
+        logits, cache = model.prefill(params, {"tokens": tokens},
+                                      cache_len=cache_len)
+        first = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
+        return first, cache
 
-    def make_decode(b):
-        def fn(cache, tok):
-            lg, cache = model.decode_step(params, cache, tok[:, None])
-            nxt = jnp.argmax(lg[:, :vocab], axis=-1).astype(jnp.int32)
-            return nxt, cache
-        return jax.jit(fn)
+    @jax.jit
+    def decode(params, cache, tok):
+        lg, cache = model.decode_step(params, cache, tok[:, None])
+        nxt = jnp.argmax(lg[:, :vocab], axis=-1).astype(jnp.int32)
+        return nxt, cache
 
     prefill_fns, decode_fns = {}, {}
     for b in b_set:
-        pf, df = make_prefill(b), make_decode(b)
+        pf, df = partial(prefill, params), partial(decode, params)
         for c in c_set:
             prefill_fns[(c, b)] = pf
             decode_fns[(c, b)] = df
@@ -102,8 +102,8 @@ def warmup_token_fns(prefill_fns: Dict, decode_fns: Dict,
                      prompt_len: int) -> None:
     """Compile every (c, b) entry of both tables (deploy-time pass —
     this is what makes the later resize in-place).  Entries sharing one
-    jitted function (every c maps to the same fn per b on this CPU
-    container) are compiled once, not once per c."""
+    function (every c maps to the same fn per b) are compiled once, not
+    once per c."""
     seen: set[int] = set()
     for (c, b), pf in prefill_fns.items():
         if id(pf) in seen:
@@ -275,7 +275,9 @@ def run_token_jax_scenario(name: str, *, requests: int = 24, seed: int = 0,
     (prompts truncated to the table's ``prompt_len`` bucket, decode
     streams clipped to ``max_decode`` — the executable-table budget),
     serves them through :func:`make_token_live_server`, and returns
-    ``(RunReport, stats)``.
+    ``(RunReport, stats)``; ``stats["backend"]`` and ``stats["requests"]``
+    hold the backend (its tables and generated ids) and the served
+    requests.
     """
     from repro.serving.scenarios import build_scenario
     batch, meta = build_scenario(name, requests=requests, seed=seed,
@@ -302,5 +304,6 @@ def run_token_jax_scenario(name: str, *, requests: int = 24, seed: int = 0,
              "events": runner.events_processed,
              "run_wall_s": time.perf_counter() - t0,
              "tokens_executed": backend.tokens_served,
-             "cost_r2": (cost.r2_prefill, cost.r2_decode), "meta": meta}
+             "cost_r2": (cost.r2_prefill, cost.r2_decode), "meta": meta,
+             "backend": backend, "requests": [r for r, _ in arrivals]}
     return report, stats

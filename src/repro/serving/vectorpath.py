@@ -83,7 +83,7 @@ from repro.serving.workload import RequestBatch
 _INF = float("inf")
 
 
-# spongelint: inline-of repro.core.monitor.array_window_rate pin=48cc23b00a85
+# spongelint: inline-of repro.core.monitor.array_window_rate pin=d7ae0c3ba57b
 def _lam_at(a: np.ndarray, ai: int, w0: int, now: float,
             window_s: float, prior: float) -> float:
     """:func:`repro.core.monitor.array_window_rate` with the window
@@ -306,7 +306,7 @@ class VectorSimRunner(FastSimRunner):
                 adv(nt, True, ai)
             if nt + 1e-12 >= sc._next_t:        # SpongeScaler.due
                 # λ — _lam_at inlined
-                # spongelint: inline-of repro.serving.vectorpath._lam_at pin=6a807a195429
+                # spongelint: inline-of repro.serving.vectorpath._lam_at pin=1dceeb6ce200
                 if ai == w0:
                     obs = 0.0
                 elif ai - w0 == 1:
@@ -325,8 +325,8 @@ class VectorSimRunner(FastSimRunner):
                     wait0 = 0.0
                 # the scaler's decide() arithmetic down to the memo
                 # solver's _quantize, scalarized:
-                # spongelint: inline-of repro.core.scaler.SpongeScaler.decide pin=23615dcd0615
-                # spongelint: inline-of repro.core.solver.MemoizedSolver.solve pin=f62550972488
+                # spongelint: inline-of repro.core.scaler.SpongeScaler.decide pin=971065a46bee
+                # spongelint: inline-of repro.core.solver.MemoizedSolver.solve pin=d56a6198a630
                 lam_eff = lam * lh
                 lam_q = ceil(lam_eff / lq) * lq if lq > 0 \
                     else float(lam_eff)
@@ -388,7 +388,7 @@ class VectorSimRunner(FastSimRunner):
                     s.core_seconds += s.c * (nt - s._last_t)
                     s._last_t = nt
                 # single-slot resize from FastSimRunner._apply:
-                # spongelint: inline-of repro.serving.fastpath.FastSimRunner._apply pin=e4a54f71d7e5
+                # spongelint: inline-of repro.serving.fastpath.FastSimRunner._apply pin=ebcfdaced5a1
                 if s.c != c:
                     s.c = c
                     if pen:

@@ -30,7 +30,8 @@ from repro.train.optimizer import OptConfig
 
 arch = %(arch)r
 cfg = get_config(arch, reduced=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 B, S = 4, 16
 batch = make_batch(cfg, B, S + (cfg.num_patch_tokens or 0), 0)

@@ -1,5 +1,6 @@
 """Pallas kernel shape/dtype sweeps against pure-jnp oracles
-(interpret=True on this CPU container; TPU is the target)."""
+(interpret=True on the CPU backend; the TPU compile of the same kernels
+is checked in test_tpu_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,8 +28,8 @@ def tol(dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_sweep(b, kv, g, d, s, block_s, dtype, rng):
     q = jnp.asarray(rng.standard_normal((b, kv, g, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, kv, s, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, kv, s, d)), dtype)
     lens = jnp.asarray(rng.integers(1, s + 1, (b,)), jnp.int32)
     out = decode_attention(q, k, v, lens, block_s=block_s)
     ref = decode_attention_ref(q, k, v, lens)
@@ -40,12 +41,12 @@ def test_decode_attention_length_masking(rng):
     """Tokens beyond the valid length must not influence the output."""
     b, kv, g, d, s = 2, 2, 2, 64, 128
     q = jnp.asarray(rng.standard_normal((b, kv, g, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
     lens = jnp.asarray([40, 80], jnp.int32)
     out1 = decode_attention(q, k, v, lens, block_s=64)
-    k2 = k.at[:, 100:].set(999.0)
-    v2 = v.at[:, 100:].set(-999.0)
+    k2 = k.at[:, :, 100:].set(999.0)
+    v2 = v.at[:, :, 100:].set(-999.0)
     out2 = decode_attention(q, k2, v2, lens, block_s=64)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
@@ -240,8 +241,8 @@ def test_swa_prefill_window_one_is_self_attention(rng):
 def test_decode_attention_ragged_lengths(b, kv, g, d, s, block_s, lens,
                                          rng):
     q = jnp.asarray(rng.standard_normal((b, kv, g, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
     ln = jnp.asarray(lens, jnp.int32)
     out = decode_attention(q, k, v, ln, block_s=block_s)
     ref = decode_attention_ref(q, k, v, ln)
@@ -250,14 +251,14 @@ def test_decode_attention_ragged_lengths(b, kv, g, d, s, block_s, lens,
 
 
 def test_decode_attention_length_one_reads_first_token(rng):
-    """length=1 must return exactly V[:, 0] regardless of cache noise."""
+    """length=1 must return exactly V[:, :, 0] regardless of cache noise."""
     b, kv, g, d, s = 1, 2, 2, 32, 64
     q = jnp.asarray(rng.standard_normal((b, kv, g, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
     out = decode_attention(q, k, v, jnp.asarray([1], jnp.int32),
                            block_s=32)
-    expect = np.broadcast_to(np.asarray(v)[:, 0][:, :, None, :],
+    expect = np.broadcast_to(np.asarray(v)[:, :, 0][:, :, None, :],
                              (b, kv, g, d))
     np.testing.assert_allclose(np.asarray(out), expect, atol=1e-5)
 

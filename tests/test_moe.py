@@ -96,7 +96,8 @@ cfg = dataclasses.replace(cfg, num_experts=8, num_experts_per_tok=2,
 params = init_moe(jax.random.key(0), cfg, jnp.float32)
 x = jax.random.normal(jax.random.key(1), (8, 4, 64)) * 0.5
 y_ref, _ = jax.jit(lambda p, x: moe_fwd(p, x, cfg))(params, x)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg2 = dataclasses.replace(cfg, moe_partial_ep=True)
 with mesh:
     y_ep, _ = jax.jit(lambda p, x: moe_fwd_ep(
