@@ -35,9 +35,7 @@ BENCHES = [
     ("fig3", "benchmarks.fig3_perf_model"),
     ("fig4", "benchmarks.fig4_e2e"),
     ("solver", "benchmarks.solver_bench"),
-    ("roofline", "benchmarks.roofline_report"),
     ("predictive", "benchmarks.predictive_bench"),
-    ("perf", "benchmarks.perf_iter"),
     ("ablation", "benchmarks.ablation_bench"),
     # control-plane throughput: the 1M-request scenario through the fast
     # engine vs the pre-refactor loop (see benchmarks/throughput_bench.py)

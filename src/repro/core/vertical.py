@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence
 
 from repro.core.perf_model import PerfModel
+from repro.utils.trace import span
 
 
 @dataclass
@@ -92,23 +93,26 @@ class TimedExecutor:
     """Executable table of real jitted functions keyed by (c, b) buckets.
 
     ``fns[(c, b)]`` must be ready-to-call (pre-compiled at deploy — that is
-    what makes the resize in-place).  Measures wall latency per call.
+    what makes the resize in-place).  Each call is one ``name`` span
+    (``repro.utils.trace``) around the call and its ``block_until_ready``;
+    ``last_s`` holds the wall latency of the latest call.
     """
 
-    def __init__(self, fns: Dict[tuple[int, int], Callable]):
+    def __init__(self, fns: Dict[tuple[int, int], Callable],
+                 name: str = "model.step"):
         self.fns = dict(fns)
-        self.calls: list[tuple[float, int, int, float]] = []
+        self.name = name
+        self.last_s = 0.0
 
     def warmup(self, args_for: Callable[[int, int], tuple]) -> None:
         for (c, b), fn in self.fns.items():
             fn(*args_for(c, b))  # compile
 
     def __call__(self, c: int, b: int, *args) -> Any:
-        t0 = time.perf_counter()
-        out = self.fns[(c, b)](*args)
-        out = jax_block(out)
-        dt = time.perf_counter() - t0
-        self.calls.append((t0, c, b, dt))
+        with span(self.name, c=c, b=b):
+            t0 = time.perf_counter()
+            out = jax_block(self.fns[(c, b)](*args))
+            self.last_s = time.perf_counter() - t0
         return out
 
 

@@ -105,4 +105,5 @@ def decode_attention_pallas(q, k, v, lengths, *, block_s: int = 512,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, q, k, v)

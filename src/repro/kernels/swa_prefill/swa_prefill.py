@@ -117,4 +117,5 @@ def swa_prefill_pallas(q, k, v, *, window: int, block_q: int = 256,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="swa_prefill",
     )(q, k, v)

@@ -396,13 +396,15 @@ def mtp_logits(params, hidden, tokens, cfg: ModelConfig, mesh=None):
 def prefill(params, batch: dict, cfg: ModelConfig, mesh=None,
             cache_len: Optional[int] = None):
     """Process the whole prompt; returns (last_logits, cache)."""
-    x, ctx, b, s = _assemble_inputs(params, cfg, batch, mesh)
+    with jax.named_scope("embed"):
+        x, ctx, b, s = _assemble_inputs(params, cfg, batch, mesh)
     x = _constrain(x, mesh, jax.sharding.PartitionSpec(("pod", "data"), None, None))
     cache_len = cache_len or s
     x, aux, group_caches, shared_kv = _run_groups_prefill(
         params, x, ctx, cfg, mesh, cache_len)
-    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    logits = _head(params, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
+        logits = _head(params, cfg, x)
     cache = {"groups": group_caches,
              "index": jnp.asarray(s, jnp.int32)}
     if shared_kv is not None:
@@ -423,12 +425,14 @@ def decode_step(params, cache: dict, token, cfg: ModelConfig, mesh=None,
     else:
         positions = jnp.broadcast_to(index, (b, 1)).astype(jnp.int32)
     tok_positions = positions if cfg.rope_kind != "mrope" else None
-    x = _embed(params, cfg, token, tok_positions)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, token, tok_positions)
     ctx = {"positions": positions, "enc_out": None, "causal": True,
            "mesh": mesh, "data_axes": _dp_axes(mesh), "model_axis": "model"}
     x, new_cache = _run_groups_decode(params, x, cache, index, ctx, cfg, mesh)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _head(params, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _head(params, cfg, x)
     new_cache["index"] = index + 1
     return logits[:, 0], new_cache
 

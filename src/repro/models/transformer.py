@@ -217,19 +217,28 @@ def _mla_prefill(p, h, ctx, cfg, cache_size):
     return y, cache
 
 
+def _scope(part: str) -> str:
+    """Named scope of a block's mixer or FFN in the prefill and decode
+    programs: ``attention`` for the attention mixers, else the part's own
+    name (``mlp``, ``moe``, ``mamba2``, ...)."""
+    return "attention" if part in ("attn", "swa", "mla") else part
+
+
 def block_prefill(p, x, ctx, kind, cfg: ModelConfig, mesh, cache_size):
     mixer, ffn = kind.split("+")
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
     cache: dict = {}
-    if mixer in ("attn", "swa"):
-        w = cfg.window_size if mixer == "swa" else 0
-        y, cache["kv"] = _attn_prefill(p["attn"], h, ctx, cfg, w, cache_size)
-    elif mixer == "mla":
-        y, cache["kv"] = _mla_prefill(p["mla"], h, ctx, cfg, cache_size)
-    elif mixer == "mamba2":
-        y, cache["ssm"] = m2.mamba2_fwd(p["mamba"], h, cfg, None)
-    elif mixer == "rwkv6":
-        y, cache["tmix"] = rk.rwkv6_tmix_fwd(p["tmix"], h, cfg, None)
+    with jax.named_scope(_scope(mixer)):
+        h = rms_norm(x, p["norm1"], cfg.norm_eps)
+        if mixer in ("attn", "swa"):
+            w = cfg.window_size if mixer == "swa" else 0
+            y, cache["kv"] = _attn_prefill(p["attn"], h, ctx, cfg, w,
+                                           cache_size)
+        elif mixer == "mla":
+            y, cache["kv"] = _mla_prefill(p["mla"], h, ctx, cfg, cache_size)
+        elif mixer == "mamba2":
+            y, cache["ssm"] = m2.mamba2_fwd(p["mamba"], h, cfg, None)
+        elif mixer == "rwkv6":
+            y, cache["tmix"] = rk.rwkv6_tmix_fwd(p["tmix"], h, cfg, None)
     x = x + y
     if cfg.is_encoder_decoder and ctx.get("enc_out") is not None:
         hc = rms_norm(x, p["norm_cross"], cfg.norm_eps)
@@ -244,7 +253,8 @@ def block_prefill(p, x, ctx, kind, cfg: ModelConfig, mesh, cache_size):
         x = x + y
     aux = jnp.float32(0.0)
     if ffn != "none":
-        y, aux, st = _ffn_fwd(p, x, ctx, ffn, cfg, mesh)
+        with jax.named_scope(_scope(ffn)):
+            y, aux, st = _ffn_fwd(p, x, ctx, ffn, cfg, mesh)
         if st is not None:
             cache["cmix"] = st
         x = x + y
@@ -271,36 +281,40 @@ def _cross_decode(p, x, cache, ctx, cfg):
 
 def block_decode(p, x, cache, index, ctx, kind, cfg: ModelConfig, mesh=None):
     mixer, ffn = kind.split("+")
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
     new_cache = dict(cache)
-    if mixer in ("attn", "swa"):
-        w = cfg.window_size if mixer == "swa" else 0
-        y, new_cache["kv"] = attn.attention_decode(
-            p["attn"], h, cache["kv"], index, ctx["positions"], cfg, window=w)
-    elif mixer == "mla":
-        y, new_cache["kv"] = attn.mla_decode(
-            p["mla"], h, cache["kv"], index, ctx["positions"], cfg)
-    elif mixer == "mamba2":
-        y, new_cache["ssm"] = m2.mamba2_decode(p["mamba"], h, cfg, cache["ssm"])
-    elif mixer == "rwkv6":
-        y, new_cache["tmix"] = rk.rwkv6_tmix_fwd(p["tmix"], h, cfg,
-                                                 cache["tmix"])
+    with jax.named_scope(_scope(mixer)):
+        h = rms_norm(x, p["norm1"], cfg.norm_eps)
+        if mixer in ("attn", "swa"):
+            w = cfg.window_size if mixer == "swa" else 0
+            y, new_cache["kv"] = attn.attention_decode(
+                p["attn"], h, cache["kv"], index, ctx["positions"], cfg,
+                window=w)
+        elif mixer == "mla":
+            y, new_cache["kv"] = attn.mla_decode(
+                p["mla"], h, cache["kv"], index, ctx["positions"], cfg)
+        elif mixer == "mamba2":
+            y, new_cache["ssm"] = m2.mamba2_decode(p["mamba"], h, cfg,
+                                                   cache["ssm"])
+        elif mixer == "rwkv6":
+            y, new_cache["tmix"] = rk.rwkv6_tmix_fwd(p["tmix"], h, cfg,
+                                                     cache["tmix"])
     x = x + y
     if cfg.is_encoder_decoder and "cross" in cache:
         x = x + _cross_decode(p, x, cache["cross"], ctx, cfg)
     if ffn != "none":
-        hf = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if ffn == "mlp":
-            y = mlp_fwd(p["mlp"], hf, cfg.mlp_kind)
-        elif ffn == "moe":
-            if mesh is not None:
-                y, _ = moe_fwd_ep(p["moe"], hf, cfg, mesh,
-                                  ctx["data_axes"], ctx["model_axis"])
-            else:
-                y, _ = moe_fwd(p["moe"], hf, cfg)
-        elif ffn == "rwkv_cm":
-            y, new_cache["cmix"] = rk.rwkv6_cmix_fwd(p["cmix"], hf, cfg,
-                                                     cache["cmix"])
+        with jax.named_scope(_scope(ffn)):
+            hf = rms_norm(x, p["norm2"], cfg.norm_eps)
+            if ffn == "mlp":
+                y = mlp_fwd(p["mlp"], hf, cfg.mlp_kind)
+            elif ffn == "moe":
+                if mesh is not None:
+                    y, _ = moe_fwd_ep(p["moe"], hf, cfg, mesh,
+                                      ctx["data_axes"], ctx["model_axis"])
+                else:
+                    y, _ = moe_fwd(p["moe"], hf, cfg)
+            elif ffn == "rwkv_cm":
+                y, new_cache["cmix"] = rk.rwkv6_cmix_fwd(p["cmix"], hf, cfg,
+                                                         cache["cmix"])
         x = x + y
     return x, new_cache
 

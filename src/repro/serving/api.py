@@ -54,6 +54,7 @@ from repro.core.slo import Decision, Request
 from repro.core.solver import DEFAULT_B, DEFAULT_C
 from repro.core.vertical import TimedExecutor, VerticalScaledInstance
 from repro.serving.workload import WorkloadGenerator
+from repro.utils.trace import span
 
 _sid = itertools.count()
 
@@ -353,7 +354,7 @@ class JaxBackend(_PooledBackend):
                  for r in batch]
         out = self.table(c, b, self.pad_payload(
             [it.payload for it in items], b))
-        dt = self.table.calls[-1][3]
+        dt = self.table.last_s
         for i, it in enumerate(items):
             it.result = _index_result(out, i)
             self.results.append(it)
@@ -593,8 +594,10 @@ class ScenarioRunner:
             return
         lam = self.monitor.rate.rate(now)
         wait0 = max(self.pool[0].busy_until - now, 0.0)
-        d = policy.decide(now, self.queue, lam, initial_wait=wait0)
-        self.apply_decision(d, now)
+        with span("control.decide") as s:
+            d = policy.decide(now, self.queue, lam, initial_wait=wait0)
+            self.apply_decision(d, now)
+            s.set_metadata(c=int(d.c), b=int(d.b))
 
     def submit(self, req: Request, payload: Any = None) -> None:
         self.monitor.observe_arrival(req)
@@ -833,10 +836,11 @@ def calibrate_step_fns(fns: Dict[tuple[int, int], Callable],
     """Profile every (c, b) executable once and fit the paper's l(b, c)."""
     table = TimedExecutor(fns)
     table.warmup(lambda c, b: (example_for(c, b),))   # compile pass
+    samples = []
     for (c, b) in fns:
         table(c, b, example_for(c, b))
-    return PerfModel.fit([(b, c, dt) for _, c, b, dt in table.calls],
-                         robust=robust)
+        samples.append((b, c, table.last_s))
+    return PerfModel.fit(samples, robust=robust)
 
 
 def build_llm_step_fns(model, params, c_set: Sequence[int],

@@ -68,6 +68,7 @@ from repro.core.slo import Request
 from repro.serving.api import RunReport, build_array_report
 from repro.serving.fleet import normalize_fleet_events, route_request
 from repro.serving.workload import RequestBatch
+from repro.utils.trace import span
 
 INF = float("inf")
 
@@ -338,6 +339,11 @@ class ExactSession:
     def step_until(self, t: float) -> None:
         """Advance virtual time, processing every event with time ≤ t."""
         _check_step_target(t)
+        with span("runner.step"):
+            self._step_until(t)
+        self.now = max(self.now, t)
+
+    def _step_until(self, t: float) -> None:
         r = self.runner
         pend = self._pending
         events = self._events
@@ -365,15 +371,16 @@ class ExactSession:
                 r.submit(req, payload)
             elif kind == 1:
                 self._next_tick += r.tick
-                if hasattr(r.policy, "on_tick"):
-                    r.policy.on_tick(et, r)
-                else:
-                    r.drive(r.policy, et)
+                with span("runner.tick"):
+                    if hasattr(r.policy, "on_tick"):
+                        r.policy.on_tick(et, r)
+                    else:
+                        r.drive(r.policy, et)
                 r.core_samples.append((et, r.allocated_cores))
             else:
                 heapq.heappop(events)
-            r._dispatch(et, events, self._seq)
-        self.now = max(self.now, t)
+            with span("runner.dispatch", queue=len(r.queue)):
+                r._dispatch(et, events, self._seq)
 
     def finish(self, horizon: Optional[float] = None) -> RunReport:
         """Drain to ``horizon`` (default: last arrival + 60 s) and

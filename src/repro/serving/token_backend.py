@@ -40,6 +40,11 @@ from repro.core.scaler import TokenSpongeScaler
 from repro.core.slo import Request
 from repro.core.vertical import TimedExecutor
 from repro.serving.api import ScenarioRunner, _PooledBackend
+from repro.utils.trace import span
+
+# the token backend's counters, in the order ``counters()`` gives them
+COUNTERS = ("prefill_calls", "prefill_rows", "first_tokens", "decode_calls",
+            "decode_slot_steps", "decode_tokens")
 
 
 def build_token_step_fns(model, params, c_set: Sequence[int],
@@ -64,13 +69,15 @@ def build_token_step_fns(model, params, c_set: Sequence[int],
     def prefill(params, tokens):
         logits, cache = model.prefill(params, {"tokens": tokens},
                                       cache_len=cache_len)
-        first = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return first, cache
 
     @jax.jit
     def decode(params, cache, tok):
         lg, cache = model.decode_step(params, cache, tok[:, None])
-        nxt = jnp.argmax(lg[:, :vocab], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(lg[:, :vocab], axis=-1).astype(jnp.int32)
         return nxt, cache
 
     prefill_fns, decode_fns = {}, {}
@@ -160,19 +167,31 @@ class TokenJaxBackend(_PooledBackend):
                  max_decode: int = 8, clock: str = "measured",
                  c0: Optional[int] = None, resize_penalty: float = 0.0):
         assert clock in ("measured", "modeled"), clock
-        self.pre_table = TimedExecutor(prefill_fns)
-        self.dec_table = TimedExecutor(decode_fns)
+        self.pre_table = TimedExecutor(prefill_fns, name="model.prefill")
+        self.dec_table = TimedExecutor(decode_fns, name="model.decode")
         self.cost = cost
         self.prompt_len = prompt_len
         self.max_decode = max_decode
         self.clock = clock
         self.generated: Dict[int, List[int]] = {}
-        self.tokens_served = 0
+        self._counts = dict.fromkeys(COUNTERS, 0)
         self._payloads: Dict[int, Any] = {}
         c_set = sorted({c for c, _ in prefill_fns})
         b_set = sorted({b for _, b in prefill_fns})
         super().__init__(cost, c_set, b_set, c0=c0 or max(c_set),
                          resize_penalty=resize_penalty)
+
+    def counters(self) -> Dict[str, int]:
+        """Cumulative counts of the backend's device calls (``COUNTERS``):
+        prefill calls and their rows (Σ b) and first tokens (Σ real
+        requests); decode calls, their slot-steps (Σ b) and tokens (Σ
+        live slots)."""
+        return dict(self._counts)
+
+    @property
+    def tokens_served(self) -> int:
+        """Tokens handed out: first tokens plus decode tokens."""
+        return self._counts["first_tokens"] + self._counts["decode_tokens"]
 
     def warmup(self) -> None:
         """Compile every (c, b) prefill + decode entry."""
@@ -184,43 +203,56 @@ class TokenJaxBackend(_PooledBackend):
 
     def execute(self, batch: List[Request], c: int, b: int,
                 now: float) -> float:
-        tokens = pad_prompts([self._payloads.pop(r.id, None)
-                              for r in batch], b, self.prompt_len)
-        first, cache = self.pre_table(c, b, tokens)
-        first = np.asarray(first)
-        dt = self.pre_table.calls[-1][3]
-        if self.clock == "modeled":
-            total_prompt = sum(r.prompt_tokens for r in batch)
-            dt = float(self.cost.prefill_latency(c, total_prompt))
-        t = now + dt
-        remaining = np.zeros(b, np.int64)
-        for i, r in enumerate(batch):
-            r.first_token = t
-            self.generated[r.id] = [int(first[i])]
-            self.tokens_served += 1
-            remaining[i] = min(r.decode_tokens, self.max_decode)
-            if remaining[i] == 0:
-                r.finish = t
-        tok = first
-        while (remaining > 0).any():
-            nxt, cache = self.dec_table(c, b, cache, tok)
-            nxt = np.asarray(nxt)
-            dt = self.dec_table.calls[-1][3]
-            if self.clock == "modeled":
-                dt = float(self.cost.decode_latency(
-                    c, int((remaining > 0).sum())))
-            t += dt
-            for i, r in enumerate(batch):
-                if remaining[i] <= 0:
-                    continue            # slot already left the pool
-                if dt > r.tbt_slo + 1e-12:
-                    r.tbt_violations += 1
-                self.generated[r.id].append(int(nxt[i]))
-                self.tokens_served += 1
-                remaining[i] -= 1
-                if remaining[i] == 0:
-                    r.finish = t
-            tok = nxt
+        n = self._counts
+        gang = n["prefill_calls"]
+        with span("backend.gang", gang=gang, b=b, n=len(batch),
+                  ids=" ".join(str(r.id) for r in batch)):
+            with span("backend.pad", gang=gang):
+                tokens = pad_prompts([self._payloads.pop(r.id, None)
+                                      for r in batch], b, self.prompt_len)
+            first, cache = self.pre_table(c, b, tokens)
+            with span("backend.deliver", gang=gang, step=0):
+                first = np.asarray(first)
+                dt = self.pre_table.last_s
+                if self.clock == "modeled":
+                    total_prompt = sum(r.prompt_tokens for r in batch)
+                    dt = float(self.cost.prefill_latency(c, total_prompt))
+                t = now + dt
+                remaining = np.zeros(b, np.int64)
+                for i, r in enumerate(batch):
+                    r.first_token = t
+                    self.generated[r.id] = [int(first[i])]
+                    remaining[i] = min(r.decode_tokens, self.max_decode)
+                    if remaining[i] == 0:
+                        r.finish = t
+                n["prefill_calls"] += 1
+                n["prefill_rows"] += b
+                n["first_tokens"] += len(batch)
+            tok = first
+            step = 0
+            while (remaining > 0).any():
+                nxt, cache = self.dec_table(c, b, cache, tok)
+                step += 1
+                with span("backend.deliver", gang=gang, step=step):
+                    nxt = np.asarray(nxt)
+                    live = int((remaining > 0).sum())
+                    dt = self.dec_table.last_s
+                    if self.clock == "modeled":
+                        dt = float(self.cost.decode_latency(c, live))
+                    t += dt
+                    for i, r in enumerate(batch):
+                        if remaining[i] <= 0:
+                            continue            # slot already left the pool
+                        if dt > r.tbt_slo + 1e-12:
+                            r.tbt_violations += 1
+                        self.generated[r.id].append(int(nxt[i]))
+                        remaining[i] -= 1
+                        if remaining[i] == 0:
+                            r.finish = t
+                    n["decode_calls"] += 1
+                    n["decode_slot_steps"] += b
+                    n["decode_tokens"] += live
+                tok = nxt
         return t
 
 

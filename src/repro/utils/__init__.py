@@ -1,1 +1,2 @@
-from repro.utils.tree import tree_size, tree_bytes, tree_map_with_path
+"""Helpers shared across the framework: pytrees (``tree``), host spans
+(``trace``), cost and roofline arithmetic."""

@@ -101,3 +101,29 @@ def test_smollm_decode_step_compiles_for_tpu(one_chip, monkeypatch):
         assert "tpu_custom_call" in compiled.as_text()
     finally:
         jax.clear_caches()
+
+
+@pytest.mark.parametrize("kernel", ["decode_attention", "swa_prefill"])
+def test_kernels_carry_their_names_for_tpu(one_chip, kernel):
+    """Each Pallas kernel lowers under its own name, so a device trace
+    tells the two apart without knowing which program ran them."""
+    from repro.kernels.decode_attention.decode_attention import \
+        decode_attention_pallas
+    from repro.kernels.swa_prefill.swa_prefill import swa_prefill_pallas
+    b, s = 2, 512
+    bf = jnp.bfloat16
+    if kernel == "decode_attention":
+        fn = jax.jit(lambda q, k, v, lens: decode_attention_pallas(
+            q, k, v, lens, interpret=False))
+        args = (_sds((b, KV, G, D), bf, one_chip),
+                _sds((b, KV, s, D), bf, one_chip),
+                _sds((b, KV, s, D), bf, one_chip),
+                _sds((b,), jnp.int32, one_chip))
+    else:
+        fn = jax.jit(lambda q, k, v: swa_prefill_pallas(
+            q, k, v, window=s, interpret=False))
+        args = (_sds((b, KV * G, s, D), bf, one_chip),
+                _sds((b, KV, s, D), bf, one_chip),
+                _sds((b, KV, s, D), bf, one_chip))
+    text = fn.lower(*args).as_text()
+    assert f'kernel_name = "{kernel}"' in text
