@@ -120,6 +120,23 @@ class FixedWorkCostModel:
         return float(self.perf.latency(comp.prefill_tokens, c))
 
 
+def expected_longest(lengths, bs: Iterable[int]) -> dict:
+    """E[longest of b draws] from the empirical distribution of
+    ``lengths``, for each b in ``bs``: the decode steps a b-gang holds
+    its slots when each stream ends on its own length.
+
+    Over the sorted sample x_(1) <= ... <= x_(n), the longest of b draws
+    (with replacement) is x_(i) with probability (i/n)^b - ((i-1)/n)^b,
+    so the expectation is Σ x_(i) [(i/n)^b - ((i-1)/n)^b]: the mean at
+    b=1, nondecreasing in b, and at most the sample's maximum.
+    """
+    x = np.sort(np.asarray(lengths, np.float64).ravel())
+    if not x.size:
+        raise ValueError("expected_longest needs at least one length")
+    cdf = np.arange(x.size + 1, dtype=np.float64) / x.size
+    return {int(b): float(x @ np.diff(cdf ** int(b))) for b in bs}
+
+
 def as_cost_model(perf_or_cost: Union[PerfModel, CostModel]) -> CostModel:
     """Adapt a ``PerfModel`` to the :class:`CostModel` protocol (wrap it
     in :class:`FixedWorkCostModel`); pass an existing cost model through
@@ -185,9 +202,22 @@ class TokenCostModel:
         """Full-service latency of b mean-shaped requests: one prefill
         burst of ``b·mean_prompt`` tokens + ``mean_decode`` decode steps
         at concurrency b."""
+        return self.gang_latency(b, c, self.mean_decode)
+
+    def gang_latency(self, b, c, steps):
+        """Latency of a gang of b mean-prompt requests that holds its
+        slots for ``steps`` decode steps at concurrency b (its longest
+        stream, where nothing joins mid-gang)."""
         b = np.asarray(b, np.float64)
         return (self.prefill_latency(c, b * self.mean_prompt)
-                + self.mean_decode * self.decode_latency(c, b))
+                + steps * self.decode_latency(c, b))
+
+    def gang_throughput(self, b, c, steps):
+        """Requests/second of back-to-back :meth:`gang_latency` gangs;
+        at ``steps == mean_decode`` it is :meth:`throughput` bit for
+        bit."""
+        return (np.asarray(b, np.float64)
+                / np.maximum(self.gang_latency(b, c, steps), 1e-12))
 
     def latency(self, b, c):
         """PerfModel-compatible alias of :meth:`batch_latency`."""

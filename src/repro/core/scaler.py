@@ -18,7 +18,7 @@ engine applies via in-place vertical scaling.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
@@ -145,6 +145,11 @@ class TokenSpongeScaler:
     # untouched (bit-identical decisions)
     uncertainty: Optional[UncertaintyConfig] = None
     decisions: List[tuple[float, Decision]] = field(default_factory=list)
+    # decisions made with a gang-true plan, and the decode steps per b
+    # the last decision planned (see ``decide``)
+    gang_plans: int = 0
+    last_drag: Dict[int, float] = field(default_factory=dict)
+    last_gang: bool = False
     _next_t: float = 0.0
     _memo: Optional[TokenMemoizedSolver] = field(default=None, repr=False)
 
@@ -164,15 +169,19 @@ class TokenSpongeScaler:
         return self._memo
 
     def solver_stats(self) -> dict:
-        """Cache economics of the memo solver ({} before first use)."""
-        if self._memo is None:
-            return {}
-        return {"hits": self._memo.hits, "misses": self._memo.misses,
-                "hit_rate": self._memo.hit_rate}
+        """Gang-true plans made, the per-b decode steps of the last
+        decision, and the memo solver's cache economics (after its first
+        use)."""
+        stats = {"gang_plans": self.gang_plans, "drag": dict(self.last_drag)}
+        if self._memo is not None:
+            stats.update(hits=self._memo.hits, misses=self._memo.misses,
+                         hit_rate=self._memo.hit_rate)
+        return stats
 
     def decide(self, now: float, queue, lam: float,
                initial_wait: float = 0.0, active_slots: int = 0,
-               tbt_budget: Optional[float] = None) -> Decision:
+               tbt_budget: Optional[float] = None,
+               gang_steps: Optional[Dict[int, float]] = None) -> Decision:
         """One adaptation step: snapshot, solve, log, return.
 
         With a non-point :class:`~repro.core.uncertainty.
@@ -182,6 +191,13 @@ class TokenSpongeScaler:
         mean) and the TTFT headroom is multiplied by the predictor's
         running slack factor, so worsening calibration widens the
         safety margin and sustained good calibration narrows it back.
+
+        ``gang_steps`` (b -> decode steps a b-gang holds its slots) comes
+        from a runner whose backend keeps a gang's slots until its
+        longest stream ends; it plans each b by its own steps in the
+        drag and the λ check (``solve_token_bruteforce``).  It stands in
+        for the mean decode length only: a configured ``drag_steps`` or
+        uncertainty plan takes precedence.
         """
         self._next_t = now + self.adaptation_interval
         headroom, drag = self.headroom, self.drag_steps
@@ -189,6 +205,8 @@ class TokenSpongeScaler:
         if unc is not None and not unc.is_point():
             headroom = self.headroom * unc.predictor.slack_factor()
             drag = unc.drag_estimate()
+        if drag is not None:
+            gang_steps = None
         rem, toks, queue_tbt = queue.token_snapshot(now)
         remaining = np.maximum(rem - headroom, 0.0)
         tbt = queue_tbt if tbt_budget is None else min(tbt_budget, queue_tbt)
@@ -199,11 +217,20 @@ class TokenSpongeScaler:
             d = solve_token_bruteforce(
                 remaining, toks, lam_eff, self.cost, self.c_set, self.b_set,
                 initial_wait=initial_wait, tbt_budget=tbt,
-                active_slots=active_slots, drag_steps=drag)
+                active_slots=active_slots, drag_steps=drag,
+                gang_steps=gang_steps)
         else:
             d = self.memo.solve(remaining, toks, lam_eff,
                                 initial_wait=initial_wait, tbt_budget=tbt,
                                 active_slots=active_slots,
-                                drag_steps=drag)
+                                drag_steps=drag, gang_steps=gang_steps)
+        self.last_gang = gang_steps is not None
+        self.gang_plans += self.last_gang
+        if self.last_gang:
+            self.last_drag = {int(b): float(gang_steps[b])
+                              for b in self.b_set}
+        else:
+            flat = self.cost.mean_decode if drag is None else drag
+            self.last_drag = dict.fromkeys(map(int, self.b_set), float(flat))
         self.decisions.append((now, d))
         return d

@@ -58,13 +58,15 @@ extend the Algorithm-1 feasibility logic to token compositions:
   decode-slot cap the engine will run at, so this bounds the steady-state
   gap between consecutive tokens of every running request;
 * the λ constraint uses the cost model's full-service throughput
-  (prefill + whole decode stream of a mean-shaped request).
+  (prefill + whole decode stream of a mean-shaped request), or, under a
+  gang-true plan (``gang_steps``), of a b-gang that holds its slots
+  until its longest stream ends.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import replace
-from typing import List, Optional, Sequence, Union
+from typing import List, Mapping, Optional, Sequence, Union
 
 import numpy as np
 
@@ -962,6 +964,15 @@ def _group_token_sums(toks: np.ndarray, b: int) -> np.ndarray:
     return padded.reshape(g, b).sum(axis=1)
 
 
+def _token_throughput(cost: TokenCostModel, b: int, c: int,
+                      gang_steps: Optional[Mapping[int, float]]) -> float:
+    """Full-service throughput of (b, c): by the mean decode length, or
+    by the b-gang's steps under a gang-true plan."""
+    if gang_steps is None:
+        return float(cost.throughput(b, c))
+    return float(cost.gang_throughput(b, c, gang_steps[b]))
+
+
 def solve_token_bruteforce(ttft_budgets, prompt_tokens, lam: float,
                            cost: TokenCostModel,
                            c_set: Sequence[int] = DEFAULT_C,
@@ -970,7 +981,9 @@ def solve_token_bruteforce(ttft_budgets, prompt_tokens, lam: float,
                            tbt_budget: float = float("inf"),
                            active_slots: int = 0,
                            mean_decode: Optional[float] = None,
-                           drag_steps: Optional[float] = None) -> Decision:
+                           drag_steps: Optional[float] = None,
+                           gang_steps: Optional[Mapping[int, float]] = None
+                           ) -> Decision:
     """Algorithm 1 extended to token compositions — reference semantics.
 
     Iterate c ascending then b ascending and return the first (c, b)
@@ -988,6 +1001,14 @@ def solve_token_bruteforce(ttft_budgets, prompt_tokens, lam: float,
       exists — the time a full group of slots takes to turn over before
       the next group's prompts can join (default: the mean decode
       length, i.e. a slot frees when its stream finishes), else 0.
+
+    **Gang-true plan**: ``gang_steps`` maps each b to the decode steps a
+    b-gang holds its slots (its longest stream, for a backend where
+    nothing joins mid-gang).  When given, it replaces ``drag_steps`` in
+    the drag of a b-group and ``mean_decode`` in the λ check, which then
+    reads ``cost.gang_throughput(b, c, gang_steps[b])``; with every
+    entry equal to the mean decode length the decisions are exactly
+    those of the default plan.
 
     The infeasible fallback mirrors ``solve_bruteforce``: fewest
     predicted TTFT violations among λ-sustaining configs, ties broken by
@@ -1007,9 +1028,11 @@ def solve_token_bruteforce(ttft_budgets, prompt_tokens, lam: float,
             l_d = float(cost.decode_latency(c, b))
             if decode_present and l_d > tbt_budget:
                 continue
-            if lam > 0 and float(cost.throughput(b, c)) < lam:
+            thr = _token_throughput(cost, b, c, gang_steps)
+            if lam > 0 and thr < lam:
                 continue
-            drag = l_d * dsteps if decode_present else 0.0
+            steps = dsteps if gang_steps is None else gang_steps[b]
+            drag = l_d * steps if decode_present else 0.0
             ok = True
             viol = 0
             q_r = initial_wait
@@ -1031,12 +1054,13 @@ def solve_token_bruteforce(ttft_budgets, prompt_tokens, lam: float,
                 return Decision(c=c, b=b, feasible=True, solver_iters=iters,
                                 solver_time=time.perf_counter() - t0,
                                 predicted_tbt=l_d)
-            key = (viol, -float(cost.throughput(b, c)))
+            key = (viol, -thr)
             if best_fallback is None or key < best_fallback[0]:
                 best_fallback = (key, c, b, l_d)
     if best_fallback is None:       # nothing passes TBT+λ: max capacity
         c = max(c_set)
-        b = max(b_set, key=lambda bb: float(cost.throughput(bb, c)))
+        b = max(b_set, key=lambda bb: _token_throughput(cost, bb, c,
+                                                        gang_steps))
         best_fallback = ((n, 0.0), c, b, float(cost.decode_latency(c, b)))
     _, c, b, l_d = best_fallback
     return Decision(c=c, b=b, feasible=False, solver_iters=iters,
@@ -1068,6 +1092,7 @@ class TokenSolverTable:
         self.dec = np.asarray(cost.decode_latency(cc.astype(np.float64), bb),
                               np.float64)
         self.thr = np.asarray(cost.throughput(bb, cc), np.float64)
+        self._cc, self._bb = cc, bb
         self.c_flat = cc.ravel()
         self.b_flat = bb.ravel()
         self.size = self.dec.size
@@ -1077,7 +1102,9 @@ class TokenSolverTable:
               tbt_budget: float = float("inf"),
               active_slots: int = 0,
               mean_decode: Optional[float] = None,
-              drag_steps: Optional[float] = None) -> Decision:
+              drag_steps: Optional[float] = None,
+              gang_steps: Optional[Mapping[int, float]] = None
+              ) -> Decision:
         """Token-composition solve; same inputs and semantics as
         :func:`solve_token_bruteforce`."""
         t0 = time.perf_counter()
@@ -1085,11 +1112,18 @@ class TokenSolverTable:
         n = rem.size
         md = self.cost.mean_decode if mean_decode is None else mean_decode
         decode_present = active_slots > 0 or md > 0
-        dsteps = md if drag_steps is None else drag_steps
         C, B = self.dec.shape
+        if gang_steps is None:
+            thr = self.thr
+            dsteps = [md if drag_steps is None else drag_steps] * B
+        else:
+            dsteps = [gang_steps[int(b)] for b in self.bs]
+            thr = np.asarray(self.cost.gang_throughput(
+                self._bb, self._cc, np.asarray(dsteps, np.float64)[None, :]),
+                np.float64)
         tbt_ok = (self.dec <= tbt_budget) if decode_present \
             else np.ones((C, B), bool)
-        sustain = (self.thr >= lam) if lam > 0 else np.ones((C, B), bool)
+        sustain = (thr >= lam) if lam > 0 else np.ones((C, B), bool)
         feas = tbt_ok & sustain
         viol = np.zeros((C, B), np.int64)
         cf = self.cs.astype(np.float64)
@@ -1099,7 +1133,7 @@ class TokenSolverTable:
                 sums = _group_token_sums(toks, b)               # (g,)
                 lp = np.asarray(self.cost.prefill_latency(
                     cf[:, None], sums[None, :]), np.float64)    # (C, g)
-                drag = (self.dec[:, j, None] * dsteps
+                drag = (self.dec[:, j, None] * dsteps[j]
                         if decode_present else 0.0)
                 steps = lp + drag
                 # fold initial_wait into the first step so the cumulative
@@ -1125,13 +1159,13 @@ class TokenSolverTable:
             key1 = np.where(pool_flat, viol.ravel().astype(np.float64),
                             np.inf)
             cand = np.flatnonzero(key1 == key1.min())
-            thr_c = self.thr.ravel()[cand]
+            thr_c = thr.ravel()[cand]
             i = int(cand[np.flatnonzero(thr_c == thr_c.max())[0]])
             c, b = int(self.c_flat[i]), int(self.b_flat[i])
             l_d = float(self.dec.ravel()[i])
         else:                   # nothing passes TBT+λ: max capacity
             c = int(self.cs[-1])
-            j = int(np.argmax(self.thr[-1]))
+            j = int(np.argmax(thr[-1]))
             b = int(self.bs[j])
             l_d = float(self.dec[-1, j])
         return Decision(c=c, b=b, feasible=False, solver_iters=self.size,
@@ -1168,7 +1202,9 @@ class TokenMemoizedSolver(_QuantizedDecisionCache):
               tbt_budget: float = float("inf"),
               active_slots: int = 0,
               mean_decode: Optional[float] = None,
-              drag_steps: Optional[float] = None) -> Decision:
+              drag_steps: Optional[float] = None,
+              gang_steps: Optional[Mapping[int, float]] = None
+              ) -> Decision:
         """Quantize conservatively, then cache per bucket signature."""
         rem, toks = _token_edf_order(ttft_budgets, prompt_tokens)
         rem, lam_q, iw = self._quantize(rem, lam, initial_wait)
@@ -1180,10 +1216,13 @@ class TokenMemoizedSolver(_QuantizedDecisionCache):
         md = self.table.cost.mean_decode if mean_decode is None \
             else mean_decode
         decode_present = active_slots > 0 or md > 0
+        gang = (None if gang_steps is None
+                else tuple(sorted(gang_steps.items())))
         return self._cached(
             (rem.tobytes(), toks.tobytes(), lam_q, iw, tbt,
-             decode_present, drag_steps, md),
+             decode_present, drag_steps, md, gang),
             lambda: self.table.solve(
                 rem, toks, lam_q, initial_wait=iw, tbt_budget=tbt,
                 active_slots=1 if decode_present else 0,
-                mean_decode=md, drag_steps=drag_steps))
+                mean_decode=md, drag_steps=drag_steps,
+                gang_steps=gang_steps))

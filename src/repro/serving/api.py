@@ -41,12 +41,14 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
+from collections import deque
 from dataclasses import dataclass, field
-from typing import (Any, Callable, Dict, List, Optional, Protocol, Sequence,
-                    Tuple, runtime_checkable)
+from typing import (Any, Callable, Deque, Dict, List, Optional, Protocol,
+                    Sequence, Tuple, runtime_checkable)
 
 import numpy as np
 
+from repro.core.cost_model import expected_longest
 from repro.core.monitor import Monitor
 from repro.core.perf_model import PerfModel, yolov5s_like
 from repro.core.queueing import EDFQueue
@@ -57,6 +59,11 @@ from repro.serving.workload import WorkloadGenerator
 from repro.utils.trace import span
 
 _sid = itertools.count()
+
+# the gang-true plan (``ScenarioRunner.gang_steps``): decode lengths of
+# this many latest completions, and the fewest it plans from
+GANG_WINDOW = 512
+GANG_MIN_SAMPLE = 32
 
 
 # --------------------------------------------------------------------------
@@ -127,6 +134,10 @@ class _PooledBackend:
     ``Decision.scale_up_delay`` before serving), core-second accounting."""
 
     name = "base"
+    # True where a dispatched gang holds its b slots until its longest
+    # stream ends (nothing joins mid-gang): the runner then plans each b
+    # by that length (``ScenarioRunner.gang_steps``)
+    holds_gang_slots = False
 
     def __init__(self, perf: PerfModel, c_set: Sequence[int],
                  b_set: Sequence[int], c0: int = 1,
@@ -555,6 +566,10 @@ class ScenarioRunner:
         self.events_processed = 0
         self.core_samples: List[tuple[float, int]] = []
         self.bucket_log: List[tuple[float, int, int, int]] = []
+        # decode lengths of the latest completed requests, and how many
+        # of ``monitor.completed`` they have taken in
+        self._lengths: Deque[int] = deque(maxlen=GANG_WINDOW)
+        self._n_seen = 0
 
     # -- facade used by policies (legacy and new) --------------------------
     @property
@@ -587,17 +602,42 @@ class ScenarioRunner:
         self.set_batch(b)
         self.backend.apply(d, now)
 
+    def gang_steps(self, bs: Sequence[int]) -> Optional[Dict[int, float]]:
+        """Decode steps a b-gang holds its slots, for each b in ``bs``:
+        the expected longest of b decode lengths drawn from the latest
+        ``GANG_WINDOW`` completed requests (``expected_longest``); None
+        while fewer than ``GANG_MIN_SAMPLE`` have completed."""
+        done = self.monitor.completed
+        self._lengths.extend(r.decode_tokens
+                             for r in done[max(self._n_seen,
+                                               len(done) - GANG_WINDOW):])
+        self._n_seen = len(done)
+        if len(self._lengths) < GANG_MIN_SAMPLE:
+            return None
+        return expected_longest(self._lengths, bs)
+
     def drive(self, policy, now: float) -> None:
-        """Run one adaptation step of a decide-protocol policy."""
+        """Run one adaptation step of a decide-protocol policy.  Where
+        the backend holds a gang's slots until its longest stream ends,
+        the policy also gets ``gang_steps`` (see :meth:`gang_steps`)."""
         due = policy.due(now) if hasattr(policy, "due") else True
         if not due:
             return
         lam = self.monitor.rate.rate(now)
         wait0 = max(self.pool[0].busy_until - now, 0.0)
         with span("control.decide") as s:
-            d = policy.decide(now, self.queue, lam, initial_wait=wait0)
+            kw = {}
+            if getattr(self.backend, "holds_gang_slots", False):
+                steps = self.gang_steps(getattr(policy, "b_set", self.b_set))
+                if steps is not None:
+                    kw["gang_steps"] = steps
+            d = policy.decide(now, self.queue, lam, initial_wait=wait0, **kw)
             self.apply_decision(d, now)
             s.set_metadata(c=int(d.c), b=int(d.b))
+            drag = getattr(policy, "last_drag", None)
+            if drag:
+                s.set_metadata(drag=drag[int(d.b)],
+                               gang=int(policy.last_gang))
 
     def submit(self, req: Request, payload: Any = None) -> None:
         self.monitor.observe_arrival(req)

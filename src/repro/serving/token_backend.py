@@ -45,6 +45,8 @@ from repro.utils.trace import span
 # the token backend's counters, in the order ``counters()`` gives them
 COUNTERS = ("prefill_calls", "prefill_rows", "first_tokens", "decode_calls",
             "decode_slot_steps", "decode_tokens")
+# decode steps timed per (c, b) by ``calibrate_token_fns``, after a warm one
+CALIBRATION_STEPS = 8
 
 
 def build_token_step_fns(model, params, c_set: Sequence[int],
@@ -124,11 +126,17 @@ def warmup_token_fns(prefill_fns: Dict, decode_fns: Dict,
 def calibrate_token_fns(prefill_fns: Dict, decode_fns: Dict,
                         prompt_len: int, mean_prompt: float = 0.0,
                         mean_decode: float = 4.0) -> TokenCostModel:
-    """Profile both tables once per (c, b) and fit the token cost model.
+    """Profile both tables per (c, b) and fit the token cost model.
 
-    Prefill samples are (b·prompt_len tokens, c, wall); decode samples
-    are (b slots, c, wall) — the measured surface the solver then plans
-    on (run :func:`warmup_token_fns` first so compiles are excluded).
+    Prefill samples are (b·prompt_len tokens, c, wall of one call);
+    decode samples are (b slots, c, the median wall of one step over
+    ``CALIBRATION_STEPS`` consecutive steps after a warm one).  Each step
+    runs as :meth:`TokenJaxBackend.execute` runs it: the call and its
+    ``block_until_ready``, then its token fetched to the host and the
+    host array fed to the next step, so the step cost the solver plans
+    on includes the token's round trip.  Every step reads the prefill's
+    cache, so the run never outgrows the table's ``max_decode``.  Run
+    :func:`warmup_token_fns` first so compiles are excluded.
     """
     import jax
     pre_samples, dec_samples = [], []
@@ -138,9 +146,15 @@ def calibrate_token_fns(prefill_fns: Dict, decode_fns: Dict,
         first, cache = jax.block_until_ready(pf(tokens))
         pre_samples.append((float(b * prompt_len), float(c),
                             time.perf_counter() - t0))
-        t0 = time.perf_counter()
-        jax.block_until_ready(decode_fns[(c, b)](cache, first))
-        dec_samples.append((float(b), float(c), time.perf_counter() - t0))
+        df = decode_fns[(c, b)]
+        tok = np.asarray(first)
+        steps = []
+        for _ in range(CALIBRATION_STEPS + 1):
+            t0 = time.perf_counter()
+            nxt, _ = jax.block_until_ready(df(cache, tok))
+            tok = np.asarray(nxt)
+            steps.append(time.perf_counter() - t0)
+        dec_samples.append((float(b), float(c), float(np.median(steps[1:]))))
     return TokenCostModel.fit(
         pre_samples, dec_samples,
         mean_prompt=mean_prompt or float(prompt_len),
@@ -157,9 +171,13 @@ class TokenJaxBackend(_PooledBackend):
     still execute and produce real tokens).  Per-request lifecycle
     (``first_token`` / ``finish`` / ``tbt_violations``) is written here;
     generated token ids are collected in ``generated[request.id]``.
+
+    A gang keeps all b slots stepping until its longest stream ends
+    (``holds_gang_slots``), so the runner plans each b by that length.
     """
 
     name = "token-jax"
+    holds_gang_slots = True
 
     def __init__(self, prefill_fns: Dict[tuple[int, int], Callable],
                  decode_fns: Dict[tuple[int, int], Callable],

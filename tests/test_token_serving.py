@@ -1,11 +1,14 @@
 """Phase-aware autoregressive serving (ISSUE 3): token cost model,
 token-composition solver, continuous-batching engines, scenarios, and
 the satellite fixes (λ-estimator guard, shared decision resolution)."""
+import time
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # guarded hypothesis import
 
-from repro.core.cost_model import Composition, TokenCostModel
+from repro.core.cost_model import (Composition, TokenCostModel,
+                                   expected_longest)
 from repro.core.monitor import RateEstimator
 from repro.core.perf_model import yolov5s_like
 from repro.core.queueing import EDFQueue, TokenFastEDFQueue
@@ -174,6 +177,108 @@ def test_token_memo_cache_hits():
         memo.solve([0.5, 0.9], [100, 40], 12.3, initial_wait=0.01,
                    tbt_budget=0.08, active_slots=3)
     assert memo.misses == 1 and memo.hits == 4
+
+
+# --------------------------------------------------------------------------
+# gang-true plan: E[longest of b] and the solve that plans each b by it
+# --------------------------------------------------------------------------
+B_SET = (1, 4, 8, 16, 32)
+
+
+def _chat_lengths(n=512, seed=0):
+    """Decode lengths of the chat mix: log-normal, median 24, sigma 0.6,
+    clipped to [1, 128]."""
+    return lognormal_lengths(np.random.default_rng(seed), n, median=24,
+                             sigma=0.6, lo=1, hi=128)
+
+
+@pytest.mark.parametrize("sample", [[5], [3, 1], [2, 2, 7], [1, 4, 4, 9],
+                                    [6, 1, 3, 8, 2]])
+def test_expected_longest_matches_enumeration(sample):
+    import itertools
+    got = expected_longest(sample, (1, 2, 3, 4))
+    for b, v in got.items():
+        draws = list(itertools.product(sample, repeat=b))
+        assert v == pytest.approx(np.mean([max(d) for d in draws]),
+                                  rel=1e-12)
+
+
+def test_expected_longest_bounds():
+    x = _chat_lengths()
+    got = expected_longest(x, B_SET)
+    assert got[1] == pytest.approx(x.mean(), rel=1e-12)
+    vals = [got[b] for b in B_SET]
+    assert all(a <= b for a, b in zip(vals, vals[1:]))
+    assert vals[-1] <= x.max()
+    # the chat mix's gangs: ~48 steps at b=4, ~81 at b=32 against a mean
+    # of ~28
+    assert 44 < got[4] < 52 and 75 < got[32] < 90 < x.max()
+    with pytest.raises(ValueError):
+        expected_longest([], B_SET)
+
+
+def _gang_plan(rng, kind):
+    """Per-b decode steps: a random nondecreasing plan, or the mean."""
+    if kind == "mean":
+        return dict.fromkeys(C16, COST.mean_decode)
+    return dict(zip(C16, np.sort(rng.uniform(1.0, 90.0, len(C16)))))
+
+
+@pytest.mark.parametrize("kind", ["gang", "mean"])
+def test_token_solvers_agree_under_a_gang_plan(kind):
+    """Table, memo and bruteforce agree decision for decision with a
+    per-b plan; a plan equal to the mean everywhere reproduces the
+    default plan's decisions exactly."""
+    rng = np.random.default_rng(11)
+    tab = TokenSolverTable(COST)
+    memo = TokenMemoizedSolver(COST)
+    for _ in range(150):
+        rem, toks, lam, iw, tbt, act = _random_solver_inputs(rng)
+        gang = _gang_plan(rng, kind)
+        kw = dict(initial_wait=iw, tbt_budget=tbt, active_slots=act)
+        d1 = solve_token_bruteforce(rem, toks, lam, COST, gang_steps=gang,
+                                    **kw)
+        d2 = tab.solve(rem, toks, lam, gang_steps=gang, **kw)
+        d3 = memo.solve(rem, toks, lam, gang_steps=gang, **kw)
+        assert (d1.c, d1.b, d1.feasible) == (d2.c, d2.b, d2.feasible) \
+            == (d3.c, d3.b, d3.feasible)
+        assert d1.predicted_tbt == pytest.approx(d2.predicted_tbt)
+        if kind == "mean":
+            d0 = solve_token_bruteforce(rem, toks, lam, COST, **kw)
+            t0 = tab.solve(rem, toks, lam, **kw)
+            assert (d0.c, d0.b, d0.feasible, d0.predicted_tbt) \
+                == (d1.c, d1.b, d1.feasible, d1.predicted_tbt)
+            assert (t0.c, t0.b, t0.feasible, t0.predicted_tbt) \
+                == (d2.c, d2.b, d2.feasible, d2.predicted_tbt)
+
+
+def test_gang_throughput_at_the_mean_is_throughput():
+    bb, cc = np.meshgrid(np.arange(1, 33), np.arange(1, 17))
+    assert np.array_equal(COST.gang_throughput(bb, cc, COST.mean_decode),
+                          COST.throughput(bb, cc))
+
+
+def test_gang_plan_rejects_b4_above_its_knee():
+    """A chip-like surface (decode 2.5 ms + 0.1 ms per slot, prefill
+    2.5 ms + 1.76 us per token, 128-token prompts) at the chat mix's
+    lengths: by the mean b=4 sustains 34.5 req/s x 1.05, by its longest
+    stream it does not, and b=8 does."""
+    x = _chat_lengths()
+    cost = TokenCostModel(gamma_p=0.0, delta_p=1.76e-6, gamma_d=0.0,
+                          delta_d=1e-4, eps=0.0, eta=2.5e-3,
+                          mean_prompt=128.0, mean_decode=float(x.mean()))
+    gang = expected_longest(x, B_SET)
+    lam = 34.5 * 1.05
+    assert cost.throughput(4, 1) > lam > cost.gang_throughput(4, 1, gang[4])
+    assert cost.gang_throughput(8, 1, gang[8]) > lam
+    for solve in (
+            lambda **k: solve_token_bruteforce([], [], lam, cost, (1,),
+                                               B_SET, **k),
+            lambda **k: TokenSolverTable(cost, (1,), B_SET).solve(
+                [], [], lam, **k)):
+        assert solve().b == 4
+        d = solve(gang_steps=gang)
+        assert d.feasible and d.b == 8
 
 
 # --------------------------------------------------------------------------
@@ -406,3 +511,83 @@ def test_token_jax_backend_end_to_end():
     assert stats["tokens_executed"] == rep.tokens_served > 0
     assert np.isfinite(rep.ttft_p99)
     assert stats["engine"] == "token-jax"
+
+
+# --------------------------------------------------------------------------
+# gang-true plan: calibration and wiring
+# --------------------------------------------------------------------------
+class _HostToken:
+    """A decode call's token: fetching it to the host costs ``trip_s``."""
+
+    def __init__(self, b, trip_s):
+        self.b, self.trip_s = b, trip_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.trip_s)
+        return np.zeros(self.b, np.int32)
+
+
+def test_calibration_times_steps_with_the_token_round_trip():
+    """Decode samples are per step of a run of steps, each fetching its
+    token and feeding the host array back, so the fitted decode cost
+    holds the round trip that one bare call leaves out.  (The fit shares
+    one per-call overhead between the axes, so the fake prefill pays the
+    decode step's.)"""
+    from repro.serving.token_backend import (CALIBRATION_STEPS,
+                                             calibrate_token_fns)
+    call_s = {b: 0.002 + 0.0005 * b for b in (1, 2, 4, 8)}
+    trip_s = 0.003
+    fed = {b: [] for b in call_s}
+
+    def make(b):
+        def prefill(tokens):
+            time.sleep(0.005 + 1e-4 * tokens.size)
+            return np.zeros(b, np.int32), "cache"
+
+        def decode(cache, tok):
+            fed[b].append(type(tok))
+            time.sleep(call_s[b])
+            return _HostToken(b, trip_s), cache
+        return prefill, decode
+
+    pre, dec = {}, {}
+    for b in call_s:
+        pre[(1, b)], dec[(1, b)] = make(b)
+    cost = calibrate_token_fns(pre, dec, prompt_len=8, mean_decode=5.0)
+    for b, calls in fed.items():
+        assert calls == [np.ndarray] * (CALIBRATION_STEPS + 1)
+        step = float(cost.decode_latency(1, b))
+        assert call_s[b] + 0.8 * trip_s < step < 2 * (call_s[b] + trip_s)
+
+
+def _gang_runner(holds):
+    backend_cls = type("GangBackend", (TokenSimBackend,),
+                       {"holds_gang_slots": holds})
+    scaler = TokenSpongeScaler(COST, adaptation_interval=0.25)
+    runner = ScenarioRunner(scaler, backend_cls(COST, C16, C16, c0=16),
+                            tick=0.25)
+    runner.monitor.rate.prior_rps = 8
+    return scaler, runner
+
+
+def test_gang_plan_engages_only_where_the_backend_holds_gang_slots():
+    from repro.serving.api import GANG_MIN_SAMPLE
+    from repro.serving.token_backend import TokenJaxBackend
+    assert TokenJaxBackend.holds_gang_slots
+    assert not TokenSimBackend.holds_gang_slots
+    batch = _token_batch(n=200, duration=20.0, seed=5)
+    plain, runner = _gang_runner(False)
+    runner.run(batch)
+    assert plain.gang_plans == 0 and not plain.last_gang
+    assert plain.solver_stats()["drag"] == dict.fromkeys(
+        C16, COST.mean_decode)
+    gang, runner = _gang_runner(True)
+    runner.run(batch)
+    assert 0 < gang.gang_plans < len(gang.decisions)
+    assert gang.solver_stats()["gang_plans"] == gang.gang_plans
+    assert len(runner.monitor.completed) > GANG_MIN_SAMPLE
+    want = expected_longest([r.decode_tokens
+                             for r in runner.monitor.completed], C16)
+    assert gang.solver_stats()["drag"] == pytest.approx(want)
+    # the plan is the runner's window of completed requests
+    assert runner.gang_steps(C16) == pytest.approx(want)

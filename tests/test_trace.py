@@ -102,6 +102,42 @@ def test_device_calls_nest_in_their_gang(traced_run):
             assert any(o[0] <= s and e <= o[1] for o in outs), inner
 
 
+def test_decide_spans_carry_the_planned_drag(traced_run, tmp_path):
+    """``control.decide`` carries the decode steps planned for the chosen
+    b and whether the gang-true plan made them: not in the short real
+    run (fewer completions than the plan needs), and in every decision
+    of a simulated run on a gang-holding backend once enough completed."""
+    from repro.core.cost_model import TokenCostModel
+    from repro.core.scaler import TokenSpongeScaler
+    from repro.serving.api import ScenarioRunner, TokenSimBackend
+    from repro.serving.workload import RequestBatch
+    rep, stats, spans = traced_run
+    mean = stats["backend"].cost.mean_decode
+    decides = [a for *_, n, a in spans if n == "control.decide"]
+    assert decides and all(a["gang"] == 0 and a["drag"] == pytest.approx(
+        mean) for a in decides)
+
+    cost = TokenCostModel.smollm_like()
+    rng = np.random.default_rng(2)
+    batch = RequestBatch.from_send(
+        np.sort(rng.uniform(0, 20, 200)), np.full(200, 0.05), slo=1.0,
+        prompt_tokens=64, decode_tokens=rng.integers(1, 60, 200),
+        tbt_slo=0.08)
+    backend = type("GangBackend", (TokenSimBackend,),
+                   {"holds_gang_slots": True})(cost, (8, 16), (1, 4, 8),
+                                               c0=16)
+    scaler = TokenSpongeScaler(cost, c_set=(8, 16), b_set=(1, 4, 8),
+                               adaptation_interval=0.25)
+    runner = ScenarioRunner(scaler, backend, tick=0.25)
+    _, spans = _profile(lambda: runner.run(batch), tmp_path)
+    decides = [a for *_, n, a in spans if n == "control.decide"]
+    gang = [a for a in decides if a["gang"] == 1]
+    assert len(gang) == scaler.gang_plans > 0
+    assert decides[-1] == gang[-1]
+    assert gang[-1]["drag"] == pytest.approx(
+        scaler.last_drag[gang[-1]["b"]])
+
+
 def test_span_counts_match_the_backend_counters(traced_run):
     _, stats, spans = traced_run
     n = stats["backend"].counters()
