@@ -1,4 +1,5 @@
-"""Model FLOPs of the tokens a decoder serves (for ``step.mfu``).
+"""Model FLOPs of the tokens a dense decoder serves (for ``step.mfu``): the
+counts of ``bench/configs/decoder_adapter.py``.
 
 A matmul of an (n, k) by (k, m) operand is 2·n·k·m FLOPs.  Prefill of a
 p-token prompt runs every layer over the p tokens, attention over the

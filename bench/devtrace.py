@@ -12,9 +12,9 @@ Outputs, all clipped to the window:
   over the devices;
 * ``ops``: seconds per operation (its HLO name; while and conditional
   ops are left out, since their bodies' ops are counted);
-* ``kernel_s``: seconds of Pallas kernel operations per program, keyed by
-  the program (XLA module) they ran in, since both kernels are one
-  function name;
+* ``kernel_s``: seconds of Pallas kernel operations, keyed by the program
+  (XLA module) they ran in and the kernel: the operation's name without
+  its ``.N`` suffix, which is the ``pallas_call``'s name;
 * ``idle``: seconds of device idle time per host activity, each idle gap
   named by the innermost ``bench.*`` span open at its midpoint;
 * ``spans``: count of each host span that starts in the window.
@@ -24,6 +24,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 from collections import defaultdict
 
 SLICE = "bench.slice"
@@ -42,6 +43,11 @@ def find_xplane(directory: str) -> str:
 def _op_name(name: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``."""
     return name.split(" = ", 1)[0].lstrip("%")
+
+
+def _kernel_name(op: str) -> str:
+    """``decode_attention.6`` -> ``decode_attention``."""
+    return re.sub(r"\.\d+$", "", op)
 
 
 def _is_container(name: str) -> bool:
@@ -122,12 +128,13 @@ def reduce_trace(path: str) -> dict:
             if _is_container(ev.name):
                 continue
             dur = (e - s) * 1e-9
-            ops_s[_op_name(ev.name)] += dur
+            op = _op_name(ev.name)
+            ops_s[op] += dur
             if _is_kernel(ev.name, _stats(ev)):
                 i = bisect.bisect_right(mstarts, ev.start_ns) - 1
                 mod = modules[i][2] if i >= 0 and \
                     modules[i][1] >= ev.start_ns else "?"
-                kernel_s[mod] += dur
+                kernel_s[mod, _kernel_name(op)] += dur
         merged = _union(ivs)
         busy.append(sum(e - s for s, e in merged) * 1e-9)
         prev = w0

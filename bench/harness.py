@@ -65,6 +65,31 @@ class Cell:
     per_layer: list           # this cell's per-layer metric entries
     limits: dict              # the compared numbers' limits
     chips: int = 1
+    adapter: object = None    # the configuration's adapter module
+
+    def __post_init__(self):
+        if self.adapter is None:
+            self.adapter = load_adapter(self.cfg)
+
+
+def load_adapter(cfg: dict):
+    """The module ``bench/configs/<adapter>.py`` that the configuration
+    file names under ``"adapter"``: everything the harness, the weights
+    and the metrics ask of its architecture (``decoder_adapter.py`` says
+    what)."""
+    name = cfg.get("adapter")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"configuration {cfg.get('name')!r}: its file "
+                         f"names no \"adapter\" (a module of bench/configs/)")
+    module = f"bench.configs.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(f"configuration {cfg.get('name')!r}: \"adapter\" "
+                         f"{name!r} is no module bench/configs/{name}.py") \
+            from None
 
 
 def _applies(entry: dict, cell: str) -> bool:
@@ -273,49 +298,31 @@ class TraceWindow:
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-def program_config(cfg: dict):
-    """The program's ModelConfig at the configuration file's sizes, with
-    both Pallas kernel routes on."""
-    import dataclasses
-    from repro.configs import get_config
-    prog = cfg["program"]
-    layers = cfg["num_hidden_layers"]
-    return dataclasses.replace(
-        get_config(prog["arch"]), num_layers=layers,
-        d_model=cfg["hidden_size"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        blocks=(prog["block"],) * layers,
-        window_size=int(cfg.get("sliding_window") or 0),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype=cfg["torch_dtype"],
-        param_dtype=cfg["torch_dtype"], use_pallas_prefill=True,
-        use_pallas_decode=True)
-
-
 def kernel_guard(pre_fns: dict, dec_fns: dict, prompt_len: int,
-                 expect: list) -> list:
-    """Names of the expected kernels missing from the lowered entries.
+                 expect: dict) -> list:
+    """The expected kernels missing from the lowered entries.
 
-    Each entry is lowered (not run) at its own shapes; a Pallas kernel
-    lowers to a ``tpu_custom_call``, and an einsum fallback through the
-    model's shape gates has none."""
+    ``expect`` names per program (``prefill``, ``decode``) the Pallas
+    kernels each of its entries must hold.  Each entry is lowered (not run)
+    at its own shapes; a Pallas kernel lowers to a ``tpu_custom_call`` that
+    carries its ``pallas_call`` name as ``kernel_name``, and an einsum
+    fallback through the model's shape gates has none."""
     import jax
     import jax.numpy as jnp
     missing = []
     for (c, b), pf in sorted(pre_fns.items()):
         tokens = jax.ShapeDtypeStruct((b, prompt_len), jnp.int32)
-        if "prefill" in expect:
-            text = pf.func.lower(*pf.args, tokens).as_text()
-            if "tpu_custom_call" not in text:
-                missing.append(f"prefill b={b}")
-        if "decode" in expect:
+        texts = {}
+        if expect.get("prefill"):
+            texts["prefill"] = pf.func.lower(*pf.args, tokens).as_text()
+        if expect.get("decode"):
             first, cache = jax.eval_shape(pf, tokens)
             df = dec_fns[(c, b)]
-            text = df.func.lower(*df.args, cache, first).as_text()
-            if "tpu_custom_call" not in text:
-                missing.append(f"decode b={b}")
+            texts["decode"] = df.func.lower(*df.args, cache,
+                                            first).as_text()
+        missing += [f"{prog} {k} b={b}" for prog, text in texts.items()
+                    for k in expect[prog]
+                    if f'kernel_name = "{k}"' not in text]
     return missing
 
 
@@ -366,27 +373,25 @@ def drive(cell: Cell, seed: int, seconds: float, *, t_proc: float,
                                              calibrate_token_fns,
                                              warmup_token_fns)
 
-    from bench import weights
     meter = CompileMeter.get()
-    cfg, mix = cell.cfg, cell.mix
+    cfg, mix, adapter = cell.cfg, cell.mix, cell.adapter
     prog = cfg["program"]
     table = mix["table"]
     prompt_len, max_decode = int(table["prompt_len"]), int(
         table["max_decode"])
-    pcfg = program_config(cfg)
-    model = build_model(pcfg)
+    model = build_model(adapter.program_config(cfg))
     expected = jax.eval_shape(model.init, jax.random.key(0))
-    params = weights.program_params(seed, cfg, expected)
+    params = adapter.program_params(seed, cfg, expected)
     c_set, b_set = tuple(prog["c_set"]), tuple(prog["b_set"])
     pre_fns, dec_fns = build_token_step_fns(model, params, c_set, b_set,
                                             prompt_len, max_decode)
     if require_tpu:
-        missing = kernel_guard(pre_fns, dec_fns, prompt_len,
-                               prog["kernels"])
+        expect = adapter.kernels(cfg)
+        missing = kernel_guard(pre_fns, dec_fns, prompt_len, expect)
         if missing:
             raise RuntimeError(f"kernel missing from compiled entries: "
                                f"{missing}")
-        log(f"kernel guard: {prog['kernels']} present in every entry "
+        log(f"kernel guard: {expect} present in every entry "
             f"(b in {list(b_set)})")
     warmup_token_fns(pre_fns, dec_fns, prompt_len)
     traffic = gen.generate(mix, seed, seconds, cfg["vocab_size"])

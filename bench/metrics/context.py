@@ -2,20 +2,23 @@
 
 Host numbers come from the harness's wall stamps over the measured window;
 device numbers from the reduced profiler trace of the analysed slice (the
-window's last seconds) and the calls that fell inside that slice.
+window's last seconds) and the calls that fell inside that slice.  Counts
+that depend on the architecture (attention widths, the layers that run each
+kernel, model FLOPs) come from the configuration's adapter.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from bench import stats
-from bench.counts import kernels, model
+from bench.counts import kernels
 
 
 class Context:
     def __init__(self, run, peaks: dict):
         self.run = run
         self.cfg = run.cell.cfg
+        self.adapter = run.cell.adapter
         self.mix = run.cell.mix
         self.peaks = peaks
         self.trace = run.trace or {}
@@ -62,9 +65,12 @@ class Context:
     def slice_span(self):
         return self.run.slice_wall
 
-    def kernel_seconds(self, program: str) -> float:
-        return sum(v for k, v in self.trace.get("kernel_s", {}).items()
-                   if program in k)
+    def kernel_seconds(self, program: str, kernel: str) -> float:
+        """Device seconds of the Pallas kernel named ``kernel`` (or a name
+        that starts with it) inside the program ``program``."""
+        return sum(v for (mod, k), v in self.trace.get("kernel_s",
+                                                       {}).items()
+                   if program in mod and k.startswith(kernel))
 
     def roofline(self, flops: float, nbytes: float, seconds: float):
         """Percent of the chip's roofline, and which bound sets it."""
@@ -77,42 +83,42 @@ class Context:
 
     # -- counts -----------------------------------------------------------
     def heads(self):
-        c = self.cfg
-        return (c["num_attention_heads"], c["num_key_value_heads"],
-                c["head_dim"], c["num_hidden_layers"])
+        """(heads, kv_heads, head_dim, layers per kernel, window)."""
+        return self.adapter.attention(self.cfg)
 
     def decode_attention_work(self, span):
-        h, kv, hd, layers = self.heads()
+        h, kv, hd, layers, _ = self.heads()
+        n = layers.get("decode_attention", 0)
         f = by = 0.0
         for b, _, _, step, _ in self.calls("decode", span):
             ff, bb = kernels.decode_attention(b, h, kv, hd,
                                               self.prompt_len + step)
-            f += ff * layers
-            by += bb * layers
+            f += ff * n
+            by += bb * n
         return f, by
 
     def swa_prefill_work(self, span):
-        h, kv, hd, layers = self.heads()
-        w = int(self.cfg.get("sliding_window") or 0)
+        h, kv, hd, layers, w = self.heads()
+        n = layers.get("swa_prefill", 0)
         f = by = 0.0
         for b, _, _, _, _ in self.calls("prefill", span):
             ff, bb = kernels.swa_prefill(b, h, kv, hd, self.prompt_len, w)
-            f += ff * layers
-            by += bb * layers
+            f += ff * n
+            by += bb * n
         return f, by
 
     def served_model_flops(self) -> float:
         """Model FLOPs of the tokens served inside the window."""
         a, b = self.window
-        p = self.prompt_len
+        p, ad = self.prompt_len, self.adapter
         total = 0.0
         for s in self.run.rec.stamps.values():
             if not s:
                 continue
             for j, t in enumerate(s):
                 if a <= t < b:
-                    total += (model.prefill_flops(self.cfg, p) if j == 0
-                              else model.decode_flops(self.cfg, p + j - 1))
+                    total += (ad.prefill_flops(self.cfg, p) if j == 0
+                              else ad.decode_flops(self.cfg, p + j - 1))
         return total
 
     pct = staticmethod(stats.pct)

@@ -1,5 +1,6 @@
-"""Model FLOPs of the tokens served inside the window (bench/counts/model.py)
-over the window's seconds times the chip's peak bf16 FLOP/s."""
+"""Model FLOPs of the tokens served inside the window (the configuration's
+adapter counts them) over the window's seconds times the chip's peak bf16
+FLOP/s."""
 
 
 def read(ctx):

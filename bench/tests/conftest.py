@@ -41,12 +41,14 @@ def tiny_cell(rate=60.0, limit=None):
 TINY_LIMIT = 0.1
 
 
-def tiny_run(seed, fault=None, control=False, seconds=1.5, limit=TINY_LIMIT):
-    """Drive the tiny cell on the CPU and check it, as a run would."""
+def tiny_run(seed, fault=None, control=False, seconds=1.5, limit=TINY_LIMIT,
+             cell=None):
+    """Drive the tiny cell (or ``cell``) on the CPU and check it, as a run
+    would."""
     from bench import harness
     t = time.perf_counter()
-    run, _ = harness.drive(tiny_cell(limit=limit), seed, seconds, t_proc=t,
-                           fault=fault, require_tpu=False,
+    run, _ = harness.drive(cell or tiny_cell(limit=limit), seed, seconds,
+                           t_proc=t, fault=fault, require_tpu=False,
                            log=lambda *_: None)
     return run, harness.check(run, control=control, log=lambda *_: None)
 

@@ -27,9 +27,12 @@ def test_window_busy_and_idle_add_up(reduced):
 
 def test_kernels_found_by_their_program(reduced):
     k = reduced["kernel_s"]
-    prefill = [v for m, v in k.items() if "prefill" in m]
-    decode = [v for m, v in k.items() if "decode" in m]
+    prefill = [v for (m, n), v in k.items() if "prefill" in m]
+    decode = [v for (m, n), v in k.items() if "decode" in m]
     assert len(prefill) == len(decode) == 1
+    # each keyed by its kernel too: the op's name without its suffix
+    assert sorted(n for _, n in k) == ["decode_attention",
+                                       "swa_prefill_attention"]
     assert prefill[0] == pytest.approx(0.00062108, rel=1e-4)
     assert decode[0] == pytest.approx(0.002177836, rel=1e-4)
     # the decode kernel is also the op of that name in the breakdown

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import weights
+from bench.configs import decoder_adapter
 from bench.configs import decoder_reference as ref
 from bench.tests.conftest import tiny_cell
 
@@ -17,14 +17,14 @@ from bench.tests.conftest import tiny_cell
 def test_reference_matches_program_f32(window):
     from repro.models import build_model
 
-    from bench import harness
     cfg = dict(tiny_cell().cfg, sliding_window=window)
-    pc = dataclasses.replace(harness.program_config(cfg), dtype="float32",
+    pc = dataclasses.replace(decoder_adapter.program_config(cfg),
+                             dtype="float32",
                              param_dtype="float32", use_pallas_prefill=False,
                              use_pallas_decode=False)
     model = build_model(pc)
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          weights.program_params(7, cfg))
+                          decoder_adapter.program_params(7, cfg))
     tokens = np.asarray(jax.random.randint(jax.random.key(1), (3, 12), 0,
                                            cfg["vocab_size"]), np.int32)
     with jax.default_matmul_precision("highest"):

@@ -1,6 +1,6 @@
-"""The program's weights (one jitted call, all layers under vmap) equal the
-leaves the reference makes again layer by layer, and fit the program's own
-parameter tree."""
+"""The program's weights, as the decoder's adapter makes them (one jitted
+call, all layers under vmap), equal the leaves the reference makes again
+layer by layer, and fit the program's own parameter tree."""
 import dataclasses
 
 import jax
@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import weights
+from bench.configs import decoder_adapter
 from bench.tests.conftest import tiny_cell
 
 
@@ -15,11 +16,10 @@ from bench.tests.conftest import tiny_cell
 def test_program_tree_equals_per_layer_leaves(seed):
     from repro.models import build_model
 
-    from bench import harness
     cfg = tiny_cell().cfg
-    model = build_model(harness.program_config(cfg))
+    model = build_model(decoder_adapter.program_config(cfg))
     expected = jax.eval_shape(model.init, jax.random.key(0))
-    tree = weights.program_params(seed, cfg, expected)
+    tree = decoder_adapter.program_params(seed, cfg, expected)
     sizes = weights.sizes_of(cfg)
     key = weights.base_key(seed)
     g = weights.global_leaves(key, sizes)
@@ -34,17 +34,16 @@ def test_program_tree_equals_per_layer_leaves(seed):
 
 def test_seeds_give_different_weights():
     cfg = tiny_cell().cfg
-    a = weights.program_params(1, cfg)
-    b = weights.program_params(2, cfg)
+    a = decoder_adapter.program_params(1, cfg)
+    b = decoder_adapter.program_params(2, cfg)
     assert not np.array_equal(a["embed"], b["embed"])
 
 
 def test_tree_mismatch_is_refused():
     from repro.models import build_model
 
-    from bench import harness
     cfg = tiny_cell().cfg
-    pc = dataclasses.replace(harness.program_config(cfg), d_ff=256)
+    pc = dataclasses.replace(decoder_adapter.program_config(cfg), d_ff=256)
     expected = jax.eval_shape(build_model(pc).init, jax.random.key(0))
     with pytest.raises(ValueError):
-        weights.program_params(0, cfg, expected)
+        decoder_adapter.program_params(0, cfg, expected)

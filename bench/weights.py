@@ -2,10 +2,11 @@
 
 Every leaf is drawn from its own key, ``fold_in(fold_in(seed key, leaf),
 layer)``, so the same numbers come out whether a leaf is made for one
-layer or for all layers at once under ``vmap``.  The program's copy is
-made on the device in one jitted call; the plain reference makes each
-layer again from the seed when it needs it, and so takes nothing that the
-program made.
+layer or for all layers at once under ``vmap``.  A configuration's adapter
+(``bench/configs/<adapter>.py``) puts the leaves into the program's tree on
+the device in one jitted call; the plain reference makes each layer again
+from the seed when it needs it, and so takes nothing that the program
+made.
 
 Scales follow common initialisation: matrices ~ N(0, 1/fan_in), the
 embedding ~ N(0, 0.02^2), and every RMSNorm gain is ``1 + s`` with
@@ -13,8 +14,6 @@ s ~ N(0, NORM_STD^2), so the gains are exercised too.  All leaves are
 rounded to bfloat16, the type they are served in.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -79,29 +78,10 @@ def layer_leaves(key, sizes: tuple, layer) -> dict:
     return {n: _leaf(key, sizes, n, layer) for n in LAYER_LEAVES}
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _program_tree(key, sizes: tuple):
-    g = global_leaves(key, sizes)
-    layers = jax.vmap(lambda i: layer_leaves(key, sizes, i))(
-        jnp.arange(sizes[0]))
-    tree = {"embed": g["embed"], "final_norm": g["final_norm"],
-            "groups": ({"norm1": layers["attn_norm"],
-                        "attn": {k: layers[k] for k in ("wq", "wk", "wv",
-                                                        "wo")},
-                        "norm2": layers["mlp_norm"],
-                        "mlp": {k: layers[k] for k in ("w_up", "w_down",
-                                                       "w_gate")}},)}
-    if not sizes[7]:
-        tree["head"] = g["head"]
-    return tree
-
-
-def program_params(seed: int, cfg: dict, expected=None):
-    """The program's parameter tree, made on the device in one call.
-
-    ``expected`` (``jax.eval_shape`` of the program's own ``init``) is
-    checked leaf for leaf: structure, shapes and dtypes."""
-    tree = _program_tree(base_key(seed), sizes_of(cfg))
+def fit(tree, expected=None):
+    """``tree``, checked leaf for leaf against ``expected`` (``jax.eval_shape``
+    of the program's own ``init``) where that is given: structure, shapes
+    and dtypes."""
     if expected is not None:
         got = jax.tree.map(lambda a: (a.shape, a.dtype), tree)
         want = jax.tree.map(lambda a: (a.shape, a.dtype), expected)
